@@ -81,6 +81,10 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     # quick-scale and full-scale runs are different workloads; the meta block
     # keeps cross-PR comparisons scoped to like-for-like artifacts
     artifact = {

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.synthetic import make_dataset, make_queries
+from repro.launch.compile_cache import enable_compile_cache
 from repro.search import multi_query_search, subsequence_search
 from repro.search.subsequence import VARIANTS
 from repro.serve import StreamSearchEngine
@@ -67,6 +68,7 @@ def main() -> None:
     ap.add_argument("--window-ratio", type=float, default=0.1)
     ap.add_argument("--dataset", default="ECG")
     args = ap.parse_args()
+    enable_compile_cache()
 
     ref = jnp.asarray(make_dataset(args.dataset, args.ref_len, seed=0), jnp.float32)
     q = jnp.asarray(make_queries(args.dataset, 1, args.query_len, seed=1)[0], jnp.float32)

@@ -7,9 +7,9 @@ of candidates?* Two real backends exist:
       ``(candidate_blocks, row_blocks)`` grid with the DP carry in VMEM and a
       block-level early-exit flag. Rows advance in lockstep across the lanes
       of a block, so abandon granularity is the block — coarser than the JAX
-      path but with none of vmap's per-lane while_loop degradation. On
-      non-TPU platforms the same kernel runs in interpret mode (Python
-      execution of the kernel body) — correct everywhere, fast only on TPU.
+      path but with none of vmap's per-lane while_loop degradation. It
+      lowers through Mosaic and exists only on the TPU: asking for it on
+      another platform raises instead of quietly running the interpreter.
 
   ``jax`` — ``core.ea_pruned_dtw.ea_pruned_dtw_banded`` under ``vmap``: a
       per-lane banded ``lax.while_loop``. Under vmap every lane steps until
@@ -25,8 +25,9 @@ Selection order:
      argument is ``None`` / ``"auto"`` is passed through it,
   3. platform default: ``pallas`` on TPU, ``jax`` elsewhere.
 
-``pallas_interpret`` forces interpret mode on any platform — the CI path
-that exercises the kernel's exact program on CPU. Multivariate queries
+``pallas_interpret`` runs the same kernel in interpret mode (Python
+execution of the kernel body) on any platform — the CI path that exercises
+the kernel's exact program on CPU. It runs only when asked for. Multivariate queries
 (``query.ndim > 1``) always take the ``jax`` backend; the kernel is
 univariate (the paper's workload).
 
@@ -53,11 +54,19 @@ def resolve_backend(backend: str | None = None) -> str:
 
     ``None`` defers to ``$REPRO_DTW_BACKEND`` (default ``auto``); ``auto``
     picks ``pallas`` on TPU and ``jax`` elsewhere. Returns one of
-    ``("pallas", "pallas_interpret", "jax")``.
+    ``("pallas", "pallas_interpret", "jax")``. Raises ``ValueError`` for an
+    unknown name, and for ``pallas`` off the TPU.
     """
     b = backend if backend is not None else os.environ.get(ENV_VAR, "auto")
     if b not in BACKENDS:
         raise ValueError(f"backend {b!r} not in {BACKENDS}")
+    on_tpu = jax.default_backend() == "tpu"
     if b == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jax"
+        return "pallas" if on_tpu else "jax"
+    if b == "pallas" and not on_tpu:
+        raise ValueError(
+            f"backend 'pallas' lowers through Mosaic and needs a TPU (this "
+            f"platform is {jax.default_backend()!r}); use 'pallas_interpret' "
+            f"to run the kernel in interpret mode, or 'jax'"
+        )
     return b

@@ -27,9 +27,9 @@ two implementations:
     (``kernels.ops.dtw_ea`` / ``dtw_ea_multi``). Tuning knobs: ``band_width``
     (columns per row, lane-aligned default), ``block_k`` (candidate lanes per
     grid block — the early-exit granularity), ``row_block`` (DP rows per
-    sequential grid step). ``pallas`` lowers through Mosaic on TPU and falls
-    back to interpret mode elsewhere; ``pallas_interpret`` forces interpret
-    mode (the CPU test path for the kernel program).
+    sequential grid step). ``pallas`` lowers through Mosaic and exists only
+    on the TPU (elsewhere it raises); ``pallas_interpret`` runs interpret
+    mode on any platform (the CPU test path for the kernel program).
   * ``backend="jax"`` — per-lane banded ``lax.while_loop`` under ``vmap``
     (CPU/GPU fallback, float64-capable reference), with ``ub`` vmapped per
     lane so the semantics match the kernel exactly. Tuning knobs:
@@ -228,7 +228,7 @@ def ea_pruned_dtw_batch(
             with_info,
         )
         return out
-    interpret = True if resolved == "pallas_interpret" else None
+    interpret = resolved == "pallas_interpret"
     out = _kernel_ops().dtw_ea(
         query, candidates, ub, window, cb=cb, band_width=band_width,
         block_k=block_k, row_block=row_block, interpret=interpret,
@@ -279,7 +279,7 @@ def ea_pruned_dtw_multi_batch(
             queries, candidates, ub, window, band_width, cb, rows_per_step,
             with_info,
         )
-    interpret = True if resolved == "pallas_interpret" else None
+    interpret = resolved == "pallas_interpret"
     out = _kernel_ops().dtw_ea_multi(
         queries, candidates, ub, window, cb=cb, band_width=band_width,
         block_k=block_k, row_block=row_block, interpret=interpret,
@@ -362,7 +362,6 @@ def ea_pruned_dtw_multi_batch_fused(
     block_k: int = 8,
     row_block: int = 128,
     with_info: bool = False,
-    ref_budget: int | None = None,
 ):
     """Fused-gather ``ea_pruned_dtw_multi_batch``: no candidate slab.
 
@@ -384,8 +383,6 @@ def ea_pruned_dtw_multi_batch_fused(
         Pallas round kernel builds it in-kernel with a tree-order suffix
         sum: the documented O(1)-ulp reformulation; the jax path is
         bit-identical to the gathered jax path).
-      ref_budget: Pallas-only — VMEM byte budget for the reference operand
-        (above it the kernel DMA-streams windows from HBM).
 
     Returns: as ``ea_pruned_dtw_multi_batch``.
     """
@@ -412,12 +409,12 @@ def ea_pruned_dtw_multi_batch_fused(
             queries, ref, starts, mu_l, sg_l, ub, u_arr, low_arr, window,
             length, band_width, rows_per_step, with_info, use_cb,
         )
-    interpret = True if resolved == "pallas_interpret" else None
+    interpret = resolved == "pallas_interpret"
     out = _kernel_ops().dtw_ea_multi_fused(
         queries, ref, starts, mu_l, sg_l, ub, window, length,
         u=u, low=low, use_cb=use_cb, band_width=band_width,
         block_k=block_k, row_block=row_block, interpret=interpret,
-        with_info=with_info, ref_budget=ref_budget,
+        with_info=with_info,
     )
     if with_info:
         d, rows, cells = out
@@ -592,7 +589,7 @@ def ea_pruned_dtw_persistent(
             jnp.asarray(ub_init, dt), u_arr, low_arr,
             window, band_width, rows_per_step, block_k, use_cb,
         )
-    interpret = True if resolved == "pallas_interpret" else None
+    interpret = resolved == "pallas_interpret"
     return _kernel_ops().dtw_ea_persistent(
         queries, candidates, lb, starts, ub_init, window, u=u, low=low,
         use_cb=use_cb, band_width=band_width, block_k=block_k,
@@ -712,7 +709,6 @@ def ea_pruned_dtw_persistent_fused(
     backend: str | None = None,
     block_k: int = 8,
     row_block: int = 128,
-    ref_budget: int | None = None,
 ):
     """Fused-gather persistent sweep: whole search, O(N + K) operands.
 
@@ -727,7 +723,6 @@ def ea_pruned_dtw_persistent_fused(
       ref: ``(N,)`` raw (sanitized) reference series.
       mu, sigma: full ``(N_win,)`` per-window stats tables (``sigma`` raw;
         clamped at this boundary).
-      ref_budget: Pallas-only VMEM byte budget for the reference operand.
 
     Returns: ``(best_dist, best_start, blocks)`` — as the slab form.
     """
@@ -755,12 +750,11 @@ def ea_pruned_dtw_persistent_fused(
             jnp.asarray(ub_init, dt), u_arr, low_arr,
             window, length, band_width, rows_per_step, block_k, use_cb,
         )
-    interpret = True if resolved == "pallas_interpret" else None
+    interpret = resolved == "pallas_interpret"
     return _kernel_ops().dtw_ea_persistent_fused(
         queries, ref, lb_arr, starts_arr, mu_l, sg_l, ub_init, window,
         length, u=u, low=low, use_cb=use_cb, band_width=band_width,
         block_k=block_k, row_block=row_block, interpret=interpret,
-        ref_budget=ref_budget,
     )
 
 

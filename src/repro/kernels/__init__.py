@@ -7,7 +7,8 @@
               SMEM abandon flag, optional rows/cells pruning counters)
   lb_keogh  — LB_Kim + LB_Keogh for every window of a reference in one pass
 
-``ops.py`` holds the jitted wrappers (interpret=True on CPU, Mosaic on TPU):
+``ops.py`` holds the jitted wrappers (Mosaic by default; ``interpret=True``
+runs the kernel body in Python, the CPU test path):
 ``dtw_ea_multi`` is the multi-query launch, ``dtw_ea`` its Q = 1 form, and
 ``dtw_ea_persistent`` the one-launch-per-search persistent form (sequential
 candidate grid dimension, incumbent carried in SMEM scratch);

@@ -83,23 +83,33 @@ operand form no longer ships pre-gathered ``(block_k, m)`` normalized
 windows. Instead the kernels take the **raw reference series** — resident
 once, O(N) — plus per-lane ``(start, mu, sigma)`` vectors, and each block's
 ``_init`` phase slices its lanes' windows out of the series and normalizes
-them into VMEM scratch (``_gather_norm_block``): per-lane lane-uniform
-``pl.ds(start, m)`` copies (the Python loop over the static ``block_k`` lane
-index unrolls at trace time) followed by one vectorized
-``(cand - mu) / sigma``. ``sigma`` arrives pre-clamped by the host wrapper
-(``clamp_sigma``), so flat windows normalize to exactly the same zeros as
-the retired host-side slab. For references too large to hold in VMEM the
-reference operand stays in HBM (``memory_space=ANY``) and the per-lane
-window copies become explicit DMAs (``make_async_copy`` + a DMA semaphore)
-— the slab-streaming tier (``ref_in_vmem=False``). The working set drops
+them into VMEM scratch (``_gather_norm_block``): one DMA per lane of the
+128-aligned span that covers its window (the Python loop over the static
+``block_k`` lane index unrolls at trace time), a lane rotation that moves
+the window to lane 0, then one vectorized ``(cand - mu) / sigma``.
+``sigma`` arrives pre-clamped by the host wrapper (``clamp_sigma``), so
+flat windows normalize to exactly the same zeros as the retired host-side
+slab. The reference stays in HBM (``memory_space=ANY``) at every size; only
+the DMA'd spans occupy VMEM. The working set drops
 from O(N·l) (every overlapping window re-copied) to O(N + block_k·m), which
 is what lets persistent mode sweep references whose window slab could never
 be materialized. The UCR ``cb`` suffix is likewise built in-kernel from the
 just-normalized tile (LB_Keogh terms + tree-order suffix sum — the same
 documented O(1)-ulp reformulation as the persistent prologue below).
 
-Validated against ``ref.py`` and the banded JAX path in interpret mode on
-CPU; written for TPU as the target.
+Mosaic alignment rules (what the TPU compiler accepts): a vector load or
+a DMA may start only at a lane offset that is provably a multiple of 128,
+and a rank-1 block must be 128 long. So every unaligned dynamic column
+range (a lane's window, the DP band at ``lo``, the ``cb`` column) is read
+as the covering aligned span and rotated into place (``_rotate_left``, an
+exact bit move); query samples and window starts are SMEM scalars;
+per-lane results are ``(block_k, 1)`` blocks and per-query results
+``(1, 128)`` rows. Interpret mode runs the same program, so the interpret
+parity tests pin its winners and pruning logic. They do not pin the chip's
+float32 bits: on the same inputs a TPU v5e's distances differ from interpret
+mode's by up to ~2e-4 relative at l=1024, because the prefix-scan rows
+amplify a one-ulp difference in a normalized sample. ``chip_smoke.py``
+checks the chip's distances against a float64 DTW.
 """
 from __future__ import annotations
 
@@ -110,6 +120,52 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.common import BIG, DEAD_LANE_UB
 from repro.core.lower_bounds import _lb_keogh_terms
+
+
+LANES = 128  # TPU vector lane count: the alignment unit of loads and DMAs
+
+
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def span_width(width: int) -> int:
+    """Lanes of the aligned span that covers ``width`` columns at any offset."""
+    return _round_up(width + LANES - 1, LANES)
+
+
+def cb_tile_width(m: int) -> int:
+    """Lane width of a ``cb`` tile: room for the aligned span ``_cols``
+    loads to read any column ``j < m``."""
+    return _round_up(m, LANES)
+
+
+def cand_tile_width(m: int, band_width: int) -> int:
+    """Lane width of a candidate tile: ``m`` columns plus room for the
+    aligned span ``_dp_row`` loads to cut out the band at any ``lo``."""
+    w = cb_tile_width(m)
+    if band_width < m:
+        lo_base_max = (m - band_width) // LANES * LANES
+        w = max(w, lo_base_max + span_width(band_width))
+    return w
+
+
+def _rotate_left(x: jax.Array, off) -> jax.Array:
+    """Rotate the last axis left by a dynamic ``off`` (exact; no arithmetic)."""
+    n = x.shape[-1]
+    return pltpu.roll(x, (n - off) % n, x.ndim - 1)
+
+
+def _cols(ref, start, width: int) -> jax.Array:
+    """``ref[:, start:start + width]`` for a dynamic, unaligned ``start``.
+
+    Loads the 128-aligned span covering the columns and rotates them to
+    lane 0 — the form Mosaic accepts for an unaligned dynamic lane slice.
+    The caller guarantees the span lies inside ``ref``.
+    """
+    base = pl.multiple_of(start // LANES * LANES, LANES)
+    x = ref[:, pl.ds(base, span_width(width))]
+    return _rotate_left(x, start - base)[:, :width]
 
 
 def _shift_right(x: jax.Array, off: int, fill: float) -> jax.Array:
@@ -155,55 +211,63 @@ def _prefix_min(x: jax.Array) -> jax.Array:
 
 
 def _gather_norm_block(
-    ref_ref,      # (1, N_pad) raw reference (VMEM, or HBM when not ref_in_vmem)
-    starts_ref,   # (block_k, 1) int32 window start per lane
+    ref_ref,      # (1, N_pad) raw reference, left in HBM
+    starts_ref,   # (1, block_k) int32 SMEM window start per lane
     mu_ref,       # (block_k, 1) per-lane window mean
     sg_ref,       # (block_k, 1) per-lane window sigma (pre-clamped)
-    cand_ref,     # (block_k, m) VMEM scratch: normalized windows out
-    sem,          # DMA semaphore scratch (used iff not ref_in_vmem)
+    cand_ref,     # (block_k, >= m) VMEM scratch: normalized windows out
+    stage_ref,    # (block_k, 1, span_width(m)) VMEM scratch: aligned spans
+    sems,         # (block_k,) DMA semaphores, one per lane
     *,
-    ref_in_vmem: bool,
+    m: int,
 ):
     """Slice + z-normalize one block's candidate windows in-kernel.
 
-    The fused replacement for the host-side ``gather_norm_windows`` slab:
-    each lane's window is a contiguous ``pl.ds(start, m)`` slice of the
-    O(N)-resident reference. The lane index is static (the Python loop
-    unrolls at trace time), so only the slice *start* is dynamic — a
-    supported lane-uniform dynamic slice per unrolled step. VMEM tier copies
-    directly; the HBM tier (reference too large for VMEM) issues an explicit
-    DMA per lane. Normalization is one vectorized step over the whole tile;
-    ``sg_ref`` is pre-clamped on the host (``clamp_sigma``), making the
-    output bit-identical to the retired pre-gathered slab.
+    The fused replacement for the host-side ``gather_norm_windows`` slab.
+    Each lane's window ``ref[start:start + m]`` is fetched as the 128-aligned
+    span that covers it (Mosaic refuses DMAs at unaligned lane offsets):
+    all ``block_k`` DMAs are started, then each is awaited and its span
+    rotated so the window starts at lane 0. Every lane's DMA signals its
+    own semaphore, so a lane's wait returns only once that lane's span has
+    landed (a shared semaphore would count bytes from any copy in flight).
+    The lane index is static (the Python loops unroll at trace time).
+    Normalization is one vectorized step over the tile; ``sg_ref`` is
+    pre-clamped on the host (``clamp_sigma``), so the output is
+    bit-identical to the retired pre-gathered slab.
     """
-    block_k, m = cand_ref.shape
+    block_k = cand_ref.shape[0]
+    span = stage_ref.shape[-1]
+    copies, offs = [], []
     for k in range(block_k):
-        s = starts_ref[k, 0]
-        if ref_in_vmem:
-            cand_ref[k, :] = ref_ref[0, pl.ds(s, m)]
-        else:
-            cp = pltpu.make_async_copy(
-                ref_ref.at[0, pl.ds(s, m)], cand_ref.at[k], sem
-            )
-            cp.start()
-            cp.wait()
-    cand_ref[...] = (cand_ref[...] - mu_ref[...]) / sg_ref[...]
+        s = starts_ref[0, k]
+        base = pl.multiple_of(s // LANES * LANES, LANES)
+        cp = pltpu.make_async_copy(
+            ref_ref.at[:, pl.ds(base, span)], stage_ref.at[k], sems.at[k]
+        )
+        cp.start()
+        copies.append(cp)
+        offs.append(s - base)
+    for k in range(block_k):
+        copies[k].wait()
+        cand_ref[pl.ds(k, 1), :m] = _rotate_left(stage_ref[k], offs[k])[:, :m]
+    cand_ref[:, :m] = (cand_ref[:, :m] - mu_ref[...]) / sg_ref[...]
 
 
 def _dp_row(
     i,
-    q_i,          # (1,) query sample for DP row ``i``
-    cand_ref,     # (block_k, m) candidate block
+    q_i,          # query sample for DP row ``i`` (SMEM scalar)
+    cand_ref,     # (block_k, cand_tile_width(m, bw)) candidate block
     prev_ref,     # (block_k, bw) previous-row band scratch
     ns_ref,       # (block_k, 1) per-lane next_start scratch
     flags_ref,    # (block_k, 2) per-lane [abandoned, ok_last] scratch
     ub,           # (block_k, 1) per-lane thresholds (fixed for the block)
-    cb_ref,       # (block_k, m) cumulative LB suffix (read iff use_cb)
+    cb_ref,       # (block_k, >= round_up(m, 128)) LB suffix (iff use_cb)
     rel,          # (block_k, bw) column iota
     rows_ref,     # (block_k, 1) rows counter scratch (used iff emit_info)
     cells_ref,    # (block_k, 1) cells counter scratch (used iff emit_info)
     *,
     n_rows: int,
+    m: int,
     window: int,
     band_width: int,
     use_cb: bool,
@@ -215,7 +279,7 @@ def _dp_row(
     cell under its own threshold freezes (abandon flag), and padding rows
     (``i >= n_rows``) are no-ops.
     """
-    block_k, m = cand_ref.shape
+    block_k = cand_ref.shape[0]
     bw = band_width
     lo_max = m - bw  # 0 in full-width mode
 
@@ -224,8 +288,8 @@ def _dp_row(
     lo_prev = jnp.clip(i - 1 - window, 0, lo_max)
     shift = lo - lo_prev  # the window edge advances by 0 or 1
 
-    cand = cand_ref[:, pl.ds(lo, bw)]
-    c = (q_i[0] - cand) ** 2
+    cand = cand_ref[:, :bw] if lo_max == 0 else _cols(cand_ref, lo, bw)
+    c = (q_i - cand) ** 2
 
     cols = lo + rel
     hi = jnp.minimum(m - 1, i + window)
@@ -263,7 +327,7 @@ def _dp_row(
 
     if use_cb:
         jcb = jnp.minimum(i + window + 1, m - 1)
-        tail = cb_ref[:, pl.ds(jcb, 1)]  # (block_k, 1)
+        tail = _cols(cb_ref, jcb, 1)  # (block_k, 1)
         tail = jnp.where(i + window + 1 <= m - 1, tail, 0.0)
         thr = ub - tail
     else:
@@ -325,6 +389,7 @@ def _round_sweep(
     prev_ref, ns_ref, flags_ref, rows_ref, cells_ref, done_ref,
     *,
     n_rows: int,
+    m: int,
     window: int,
     row_block: int,
     band_width: int,
@@ -332,7 +397,7 @@ def _round_sweep(
     emit_info: bool,
 ):
     """Row sweep + finish shared by the gathered and fused round kernels."""
-    block_k, m = cand_ref.shape
+    block_k = cand_ref.shape[0]
     bw = band_width
     lo_max = m - bw  # 0 in full-width mode
 
@@ -343,40 +408,44 @@ def _round_sweep(
 
         def row(r, _):
             _dp_row(
-                ri * row_block + r, q_ref[0, pl.ds(r, 1)], cand_ref,
+                ri * row_block + r, q_ref[0, r], cand_ref,
                 prev_ref, ns_ref, flags_ref, ub, cb_ref, rel,
                 rows_ref, cells_ref,
-                n_rows=n_rows, window=window, band_width=bw,
+                n_rows=n_rows, m=m, window=window, band_width=bw,
                 use_cb=use_cb, emit_info=emit_info,
             )
             return 0
 
-        jax.lax.fori_loop(0, row_block, row, 0, unroll=False)
+        # int32 bounds: the row index stays 32-bit under jax_enable_x64.
+        jax.lax.fori_loop(
+            jnp.int32(0), jnp.int32(row_block), row, 0, unroll=False
+        )
         done_ref[0] = jnp.asarray(
-            jnp.all(flags_ref[:, 0] == 1), jnp.int32
+            jnp.all(flags_ref[:, 0:1] == 1), jnp.int32
         ).astype(jnp.int32)
 
     @pl.when(ri == pl.num_programs(2) - 1)
     def _finish():
-        ok = jnp.logical_and(flags_ref[:, 0] == 0, flags_ref[:, 1] == 1)
+        ok = jnp.logical_and(flags_ref[:, 0:1] == 0, flags_ref[:, 1:2] == 1)
         lo_fin = min(max(n_rows - 1 - window, 0), lo_max)  # static
-        last = prev_ref[:, (m - 1) - lo_fin]
-        out_ref[...] = jnp.where(ok, last, jnp.inf)
+        col = (m - 1) - lo_fin
+        out_ref[...] = jnp.where(ok, prev_ref[:, col : col + 1], jnp.inf)
         if emit_info:
-            rows_out[...] = rows_ref[:, 0]
-            cells_out[...] = cells_ref[:, 0]
+            rows_out[...] = rows_ref[...]
+            cells_out[...] = cells_ref[...]
 
 
 def _dtw_ea_kernel(
-    # VMEM operands
+    # operands
     ub_ref,      # (block_k, 1) per-lane upper bounds
-    q_ref,       # (1, row_block) query slice for this (query, row) block
-    cand_ref,    # (block_k, m) candidate block (lanes share one query)
-    cb_ref,      # (block_k, m) cumulative LB suffix (zeros if disabled)
+    q_ref,       # (1, row_block) SMEM query slice for this (query, row) block
+    cand_ref,    # (block_k, tile) candidate block (lanes share one query)
+    cb_ref,      # (block_k, >= m) cumulative LB suffix (zeros if disabled)
     # outputs
-    out_ref,     # (block_k,) distances
+    out_ref,     # (block_k, 1) distances
     *rest,       # [rows_out, cells_out] if emit_info, then scratch
     n_rows: int,
+    m: int,
     window: int,
     row_block: int,
     band_width: int,
@@ -403,7 +472,7 @@ def _dtw_ea_kernel(
     _round_sweep(
         ri, ub_ref, q_ref, cand_ref, cb_ref, out_ref, rows_out, cells_out,
         prev_ref, ns_ref, flags_ref, rows_ref, cells_ref, done_ref,
-        n_rows=n_rows, window=window, row_block=row_block,
+        n_rows=n_rows, m=m, window=window, row_block=row_block,
         band_width=band_width, use_cb=use_cb, emit_info=emit_info,
     )
 
@@ -411,23 +480,23 @@ def _dtw_ea_kernel(
 def _dtw_ea_fused_kernel(
     # operands
     ub_ref,      # (block_k, 1) per-lane upper bounds
-    q_ref,       # (1, row_block) query slice for this (query, row) block
-    ref_ref,     # (1, N_pad) raw reference (VMEM, or HBM when streaming)
-    starts_ref,  # (block_k, 1) int32 window start per lane
+    q_ref,       # (1, row_block) SMEM query slice for this (query, row) block
+    ref_ref,     # (1, N_pad) raw reference, left in HBM
+    starts_ref,  # (1, block_k) int32 SMEM window start per lane
     mu_ref,      # (block_k, 1) per-lane window mean
     sg_ref,      # (block_k, 1) per-lane window sigma (pre-clamped)
     u_ref,       # (1, m) query envelope upper (read iff use_cb)
     low_ref,     # (1, m) query envelope lower (read iff use_cb)
     # outputs
-    out_ref,     # (block_k,) distances
+    out_ref,     # (block_k, 1) distances
     *rest,       # [rows_out, cells_out] if emit_info, then scratch
     n_rows: int,
+    m: int,
     window: int,
     row_block: int,
     band_width: int,
     use_cb: bool,
     emit_info: bool,
-    ref_in_vmem: bool,
 ):
     """Fused round kernel: windows sliced + normalized in-kernel.
 
@@ -444,25 +513,19 @@ def _dtw_ea_fused_kernel(
         rest = rest[2:]
     else:
         rows_out = cells_out = None
-    if ref_in_vmem:
-        sem = None
-        (cand_ref, cb_ref, prev_ref, ns_ref, flags_ref, rows_ref,
-         cells_ref, done_ref) = rest
-    else:
-        (cand_ref, cb_ref, prev_ref, ns_ref, flags_ref, rows_ref,
-         cells_ref, done_ref, sem) = rest
+    (cand_ref, stage_ref, cb_ref, prev_ref, ns_ref, flags_ref, rows_ref,
+     cells_ref, done_ref, sems) = rest
 
     ri = pl.program_id(2)
 
     @pl.when(ri == 0)
     def _init():
         _gather_norm_block(
-            ref_ref, starts_ref, mu_ref, sg_ref, cand_ref, sem,
-            ref_in_vmem=ref_in_vmem,
+            ref_ref, starts_ref, mu_ref, sg_ref, cand_ref, stage_ref, sems, m=m
         )
         if use_cb:
-            terms = _lb_keogh_terms(cand_ref[...], u_ref[...], low_ref[...])
-            cb_ref[...] = _suffix_sum(terms)
+            terms = _lb_keogh_terms(cand_ref[:, :m], u_ref[...], low_ref[...])
+            cb_ref[:, :m] = _suffix_sum(terms)
         _round_init_scratch(
             prev_ref, ns_ref, flags_ref, rows_ref, cells_ref, done_ref,
             band_width=band_width, emit_info=emit_info,
@@ -471,7 +534,7 @@ def _dtw_ea_fused_kernel(
     _round_sweep(
         ri, ub_ref, q_ref, cand_ref, cb_ref, out_ref, rows_out, cells_out,
         prev_ref, ns_ref, flags_ref, rows_ref, cells_ref, done_ref,
-        n_rows=n_rows, window=window, row_block=row_block,
+        n_rows=n_rows, m=m, window=window, row_block=row_block,
         band_width=band_width, use_cb=use_cb, emit_info=emit_info,
     )
 
@@ -479,26 +542,26 @@ def _dtw_ea_fused_kernel(
 def _dtw_ea_persistent_kernel(
     # operands
     ub_init_ref,  # (Q,) SMEM per-query initial incumbents
-    q_ref,        # (1, row_block) query slice for this (query, row) block
+    q_ref,        # (1, row_block) SMEM query slice for this (query, row) block
     *rest,
     n_rows: int,
+    m: int,
     window: int,
     row_block: int,
     band_width: int,
     use_cb: bool,
     fused: bool = False,
-    ref_in_vmem: bool = True,
 ):
     """Whole best-first search in one launch (DESIGN.md §2.5).
 
     Operand forms (after ``ub_init``/``q``):
 
     * gathered (``fused=False``, the ``gather="slab"`` comparison arm):
-      ``cand (block_k, m)`` pre-normalized best-first windows, then
+      ``cand (block_k, tile)`` pre-normalized best-first windows, then
       ``lb, starts, u, low`` — the O(N·l) slab form.
     * fused (``fused=True``, default execution form): ``ref (1, N_pad)``
-      raw reference — VMEM, or HBM (``memory_space=ANY``) when
-      ``ref_in_vmem=False`` — then ``lb, starts, mu, sg, u, low``; the
+      raw reference, left in HBM (``memory_space=ANY``), then
+      ``lb, starts, mu, sg, u, low``; the
       candidate tile becomes VMEM scratch filled by ``_gather_norm_block``
       in each block's ``_init_block`` (gated off for skipped blocks, so a
       cascade-stopped tail costs no copies/DMAs). O(N + block_k·m) resident,
@@ -531,9 +594,8 @@ def _dtw_ea_persistent_kernel(
     if fused:
         (ref_ref, lb_ref, starts_ref, mu_ref, sg_ref, u_ref, low_ref,
          dist_ref, idx_ref, blocks_ref,
-         cand_ref, prev_ref, ns_ref, flags_ref, ubv_ref, cb_ref,
-         done_ref, ub_s, best_s, blocks_s, *maybe_sem) = rest
-        sem = maybe_sem[0] if maybe_sem else None
+         cand_ref, stage_ref, prev_ref, ns_ref, flags_ref, ubv_ref, cb_ref,
+         done_ref, ub_s, best_s, blocks_s, sems) = rest
     else:
         (cand_ref, lb_ref, starts_ref, u_ref, low_ref,
          dist_ref, idx_ref, blocks_ref,
@@ -543,7 +605,7 @@ def _dtw_ea_persistent_kernel(
     qi = pl.program_id(0)
     ci = pl.program_id(1)
     ri = pl.program_id(2)
-    block_k, m = cand_ref.shape
+    block_k = cand_ref.shape[0]
     bw = band_width
     lo_max = m - bw
 
@@ -573,10 +635,10 @@ def _dtw_ea_persistent_kernel(
             if fused:
                 # Fused tier: slice + normalize this block's windows out of
                 # the resident reference. Gated blocks (cascade stop / all
-                # lanes dead) skip the copies/DMAs entirely.
+                # lanes dead) skip the DMAs entirely.
                 _gather_norm_block(
-                    ref_ref, starts_ref, mu_ref, sg_ref, cand_ref, sem,
-                    ref_in_vmem=ref_in_vmem,
+                    ref_ref, starts_ref, mu_ref, sg_ref, cand_ref, stage_ref,
+                    sems, m=m,
                 )
             if use_cb:
                 # (1, m) envelope broadcasts over the block's lanes. The
@@ -584,8 +646,10 @@ def _dtw_ea_persistent_kernel(
                 # than the host drivers' sequential cumsum — cb rounding
                 # only shifts abandon thresholds by an ulp, which cannot
                 # change the winner (DESIGN.md §2.2/§2.5).
-                terms = _lb_keogh_terms(cand_ref[...], u_ref[...], low_ref[...])
-                cb_ref[...] = _suffix_sum(terms)
+                terms = _lb_keogh_terms(
+                    cand_ref[:, :m], u_ref[...], low_ref[...]
+                )
+                cb_ref[:, :m] = _suffix_sum(terms)
 
     @pl.when(done_ref[0] == 0)
     def _rows():
@@ -594,17 +658,20 @@ def _dtw_ea_persistent_kernel(
 
         def row(r, _):
             _dp_row(
-                ri * row_block + r, q_ref[0, pl.ds(r, 1)], cand_ref,
+                ri * row_block + r, q_ref[0, r], cand_ref,
                 prev_ref, ns_ref, flags_ref, ub, cb_ref, rel,
                 None, None,
-                n_rows=n_rows, window=window, band_width=bw,
+                n_rows=n_rows, m=m, window=window, band_width=bw,
                 use_cb=use_cb, emit_info=False,
             )
             return 0
 
-        jax.lax.fori_loop(0, row_block, row, 0, unroll=False)
+        # int32 bounds: the row index stays 32-bit under jax_enable_x64.
+        jax.lax.fori_loop(
+            jnp.int32(0), jnp.int32(row_block), row, 0, unroll=False
+        )
         done_ref[0] = jnp.asarray(
-            jnp.all(flags_ref[:, 0] == 1), jnp.int32
+            jnp.all(flags_ref[:, 0:1] == 1), jnp.int32
         ).astype(jnp.int32)
 
     @pl.when(ri == pl.num_programs(2) - 1)
@@ -624,9 +691,7 @@ def _dtw_ea_persistent_kernel(
             lane = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
             k = jnp.min(jnp.where(d == dmin, lane, block_k))  # first argmin
             ub_s[0] = dmin
-            best_s[0] = jnp.sum(
-                jnp.where(lane == k, starts_ref[...], 0), dtype=jnp.int32
-            )
+            best_s[0] = starts_ref[0, k]
 
     @pl.when(
         jnp.logical_and(
@@ -634,6 +699,6 @@ def _dtw_ea_persistent_kernel(
         )
     )
     def _emit():
-        dist_ref[...] = jnp.full((1,), ub_s[0], jnp.float32)
-        idx_ref[...] = jnp.full((1,), best_s[0], jnp.int32)
-        blocks_ref[...] = jnp.full((1,), blocks_s[0], jnp.int32)
+        dist_ref[...] = jnp.full((1, LANES), ub_s[0], jnp.float32)
+        idx_ref[...] = jnp.full((1, LANES), best_s[0], jnp.int32)
+        blocks_ref[...] = jnp.full((1, LANES), blocks_s[0], jnp.int32)

@@ -1,25 +1,32 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU backends (kernel body executed in
-Python for validation) and False on TPU (real Mosaic lowering).
+``interpret=False`` (the default) lowers through Mosaic for the TPU;
+``interpret=True`` runs the kernel body in Python, the CPU test path. No
+wrapper picks interpret mode on its own.
 
 Backend dispatch: ``dtw_ea`` / ``dtw_ea_multi`` are the Pallas side of the
 ``core.backend`` dispatch layer — similarity search reaches them through
 ``core.batch.ea_pruned_dtw_batch`` / ``ea_pruned_dtw_multi_batch`` with
 ``backend="pallas"|"pallas_interpret"`` rather than calling them directly.
-``backend="pallas"`` lowers through Mosaic on TPU (and falls back to
-interpret mode elsewhere); ``"pallas_interpret"`` forces interpret mode
-everywhere (the CPU test/CI path).
+``backend="pallas"`` lowers through Mosaic and exists only on the TPU;
+``"pallas_interpret"`` runs interpret mode on any platform (the CPU
+test/CI path).
 
 Lane layout (multi-query): ``dtw_ea_multi`` evaluates a flattened
 ``(Q × K)`` lane set in one launch. Candidates are reshaped to
-``(Q * k_pad, m)`` query-major, the grid is
+``(Q * k_pad, tile)`` query-major, the grid is
 ``(Q, cand_blocks, row_blocks)``, and each grid program's ``block_k`` lanes
 all belong to one query — the query/envelope tile is selected by the
 leading grid index while ``ub`` rides along as a per-lane
 ``(block_k, 1)`` VMEM vector. Scalar ``ub`` broadcasts to every lane;
 padding lanes (``K`` rounded up to ``block_k``) get a ``-1`` sentinel so
 they abandon on their first row and never delay a block's early exit.
+
+Block forms (the ones Mosaic accepts, see ``kernels.dtw_band``): query
+rows arrive as SMEM scalars (``_query_rows``), window starts as
+``(1, block_k)`` SMEM blocks (``_lane_starts``), query envelopes as
+``(1, m)`` rows of ``(Q, 1, m)`` arrays, per-lane results leave as
+``(block_k, 1)`` blocks and per-query results as ``(1, 128)`` rows.
 
 The banded column mode (``band_width``) mirrors
 ``core.ea_pruned_dtw.ea_pruned_dtw_banded``: ``band_width=None`` picks the
@@ -33,11 +40,10 @@ Fused operand form (DESIGN.md §2.10, the ``gather="fused"`` default):
 ``dtw_ea_multi_fused`` / ``dtw_ea_persistent_fused`` take the raw reference
 series once plus per-lane ``(start, mu, sigma)`` vectors and slice +
 z-normalize each block's windows inside the kernel — no pre-gathered
-``(Q, K, m)`` slab crosses the host→device boundary. References whose
-padded byte size exceeds ``ref_budget`` (default ``REF_VMEM_BYTES``) stay
-in HBM (``memory_space=ANY``) and the kernel streams each lane's window by
-explicit DMA. The slab-form wrappers above remain as the ``gather="slab"``
-comparison arm and the baseline cores' entry point.
+``(Q, K, m)`` slab crosses the host→device boundary. The reference stays in
+HBM (``memory_space=ANY``) at every size and the kernel DMAs each lane's
+window. The slab-form wrappers remain as the ``gather="slab"`` comparison arm and the
+baseline cores' entry point.
 """
 from __future__ import annotations
 
@@ -54,37 +60,149 @@ from repro.core.common import (
     pad_lanes_to_blocks,
 )
 from repro.kernels.dtw_band import (
+    LANES,
     _dtw_ea_fused_kernel,
     _dtw_ea_kernel,
     _dtw_ea_persistent_kernel,
+    cand_tile_width,
+    cb_tile_width,
+    span_width,
 )
 from repro.kernels.lb_keogh import _lb_kernel
 
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-# Fused-gather reference tier threshold: a (padded) reference at or below
-# this byte size rides in VMEM as a whole-array block; above it the operand
-# stays in HBM (memory_space=ANY) and the kernel DMA-streams each lane's
-# window slice. ~4 MB leaves headroom beside the per-block scratch within a
-# ~16 MB TPU VMEM. Overridable per call (``ref_budget``) — tests force the
-# DMA tier with a tiny budget.
-REF_VMEM_BYTES = 4 * 1024 * 1024
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _band(window: int, n: int, m: int, band_width: int | None):
+    """Clamp the window; resolve and validate the static band width."""
+    window = int(min(window, m))
+    if band_width is None:
+        band_width = default_band_width(window, m) if n == m else m
+    bw = int(min(band_width, m))
+    full = min(2 * window + 1, m)
+    if bw < full:
+        raise ValueError(f"band_width {bw} < 2*window+1 = {full}")
+    if bw < m and n != m:
+        raise ValueError("banded dtw_ea requires equal lengths (n == m)")
+    return window, bw
 
 
-def _pad_ref_2d(ref: jax.Array) -> jax.Array:
-    """Reference as a lane-aligned ``(1, N_pad)`` row (TPU wants 2-D)."""
+def _query_rows(queries: jax.Array, row_block: int):
+    """Queries as SMEM row blocks, plus their ``BlockSpec``.
+
+    ``(Q, n)`` is padded to whole row blocks and reshaped to
+    ``(Q * row_blocks, 1, row_block)``; grid step ``(qi, ci, ri)`` sees
+    ``(1, row_block)`` and reads DP row ``r`` as the scalar ``q_ref[0, r]``.
+    """
+    nq, n = queries.shape
+    n_pad = -(-n // row_block) * row_block
+    if n_pad != n:
+        queries = jnp.pad(queries, ((0, 0), (0, n_pad - n)))
+    nrb = n_pad // row_block
+    spec = pl.BlockSpec(
+        (None, 1, row_block),
+        lambda qi, ci, ri: (qi * nrb + ri, 0, 0),
+        memory_space=pltpu.SMEM,
+    )
+    return queries.reshape(nq * nrb, 1, row_block), spec, nrb
+
+
+def _lane_starts(starts: jax.Array, block_k: int, ncb: int):
+    """``(Q, k_pad)`` window starts as ``(1, block_k)`` SMEM blocks."""
+    blocks = starts.reshape(-1, 1, block_k)
+    spec = pl.BlockSpec(
+        (None, 1, block_k),
+        lambda qi, ci, ri: (qi * ncb + ci, 0, 0),
+        memory_space=pltpu.SMEM,
+    )
+    return blocks, spec
+
+
+def _lane_spec(block_k: int, ncb: int, width: int = 1) -> pl.BlockSpec:
+    """Block ``(qi, ci)`` of a query-major ``(Q * k_pad, width)`` array."""
+    return pl.BlockSpec((block_k, width), lambda qi, ci, ri: (qi * ncb + ci, 0))
+
+
+def _query_spec(width: int) -> pl.BlockSpec:
+    """Row ``qi`` of a ``(Q, 1, width)`` per-query array, seen as
+    ``(1, width)`` (a ``(1, width)`` block of ``(Q, width)`` is refused)."""
+    return pl.BlockSpec((None, 1, width), lambda qi, ci, ri: (qi, 0, 0))
+
+
+def _pad_cols(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the last axis to ``width`` columns."""
+    extra = width - x.shape[-1]
+    if extra == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
+
+
+def padded_ref_len(n: int, m: int) -> int:
+    """Length of the padded ``(1, N_pad)`` reference row.
+
+    Long enough that the 128-aligned span covering any window
+    ``[s, s + m)``, ``s <= n - m``, lies inside the row.
+    """
+    return max(-(-n // LANES) * LANES, (n - m) // LANES * LANES + span_width(m))
+
+
+def _ref_operand(ref: jax.Array, m: int):
+    """The reference as a padded ``(1, N_pad)`` row (TPU wants 2-D) and its
+    spec: left in HBM, read only by the kernels' window DMAs."""
     ref = jnp.asarray(ref, jnp.float32)
     n = ref.shape[0]
-    n_pad = -(-n // 128) * 128
-    if n_pad != n:
-        ref = jnp.pad(ref, (0, n_pad - n))
-    return ref[None, :]
+    ref = jnp.pad(ref, (0, padded_ref_len(n, m) - n))[None, :]
+    return ref, pl.BlockSpec(memory_space=pl.ANY)
+
+
+def _gather_scratch(block_k: int, m: int, bw: int):
+    """Candidate tile and aligned-span staging scratch of the fused kernels."""
+    return [
+        pltpu.VMEM((block_k, cand_tile_width(m, bw)), jnp.float32),
+        pltpu.VMEM((block_k, 1, span_width(m)), jnp.float32),
+    ]
+
+
+def _dma_sems(block_k: int):
+    """One DMA semaphore per lane of a block (``_gather_norm_block``)."""
+    return pltpu.SemaphoreType.DMA((block_k,))
+
+
+def _envelopes(u, low, nq: int, m: int):
+    """Query envelopes as ``(Q, 1, m)`` operands (zeros when unused)."""
+    if u is None:
+        zeros = jnp.zeros((nq, 1, m), jnp.float32)
+        return zeros, zeros
+    as3 = lambda e: jnp.asarray(e, jnp.float32).reshape(nq, 1, m)
+    return as3(u), as3(low)
+
+
+def _per_lane(out, nq: int, k_pad: int, k: int, with_info: bool):
+    """``(Q * k_pad, 1)`` kernel outputs back to ``(Q, K)``."""
+    unpad = lambda a: a.reshape(nq, k_pad)[:, :k]
+    if with_info:
+        return tuple(unpad(a) for a in out)
+    return unpad(out)
+
+
+def _per_lane_outputs(block_k: int, ncb: int, rows: int, with_info: bool):
+    """Distances (and ``rows``/``cells`` counters) as ``(block_k, 1)``
+    blocks of ``(Q * k_pad, 1)`` arrays."""
+    specs = [_lane_spec(block_k, ncb)]
+    shapes = [jax.ShapeDtypeStruct((rows, 1), jnp.float32)]
+    if with_info:
+        specs += [_lane_spec(block_k, ncb)] * 2
+        shapes += [jax.ShapeDtypeStruct((rows, 1), jnp.int32)] * 2
+        return specs, shapes
+    return specs[0], shapes[0]
+
+
+def _per_query_outputs(nq: int):
+    """``(best_dist, best_start, blocks)`` as ``(Q, 1, 128)`` rows."""
+    spec = pl.BlockSpec((None, 1, LANES), lambda qi, ci, ri: (qi, 0, 0))
+    shapes = [
+        jax.ShapeDtypeStruct((nq, 1, LANES), dt)
+        for dt in (jnp.float32, jnp.int32, jnp.int32)
+    ]
+    return [spec] * 3, shapes
 
 
 @partial(
@@ -102,7 +220,7 @@ def dtw_ea_multi(
     band_width: int | None = None,
     block_k: int = 8,
     row_block: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
     with_info: bool = False,
 ):
     """Multi-query batched EAPrunedDTW: one launch, ``Q × K`` lanes.
@@ -122,27 +240,18 @@ def dtw_ea_multi(
         ``n != m`` — band mode needs the square subsequence-search shape).
       block_k: candidate lanes per grid block (a parallel grid dim).
       row_block: DP rows per sequential grid step (early-exit granularity).
+      interpret: run the kernel body in Python (CPU tests) instead of
+        lowering through Mosaic.
       with_info: also return per-lane ``(rows, cells)`` int32 counters.
     Returns: ``(Q, K)`` float32 distances, ``+inf`` where abandoned; with
       ``with_info`` a ``(dists, rows, cells)`` tuple of ``(Q, K)`` arrays.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     queries = jnp.asarray(queries, jnp.float32)
     candidates = jnp.asarray(candidates, jnp.float32)
     nq, n = queries.shape
     q_, k, m = candidates.shape
     assert q_ == nq, (q_, nq)
-    window = int(min(window, m))
-
-    if band_width is None:
-        band_width = default_band_width(window, m) if n == m else m
-    bw = int(min(band_width, m))
-    full = min(2 * window + 1, m)
-    if bw < full:
-        raise ValueError(f"band_width {bw} < 2*window+1 = {full}")
-    if bw < m and n != m:
-        raise ValueError("banded dtw_ea requires equal lengths (n == m)")
+    window, bw = _band(window, n, m, band_width)
 
     use_cb = cb is not None
     if cb is None:
@@ -151,7 +260,6 @@ def dtw_ea_multi(
         cb_arr = jnp.asarray(cb, jnp.float32)
 
     k_pad = -(-k // block_k) * block_k
-    n_pad = -(-n // row_block) * row_block
     ub_arr = jnp.broadcast_to(jnp.asarray(ub, jnp.float32), (nq, k))
     if k_pad != k:
         candidates = jnp.pad(candidates, ((0, 0), (0, k_pad - k), (0, 0)))
@@ -159,46 +267,41 @@ def dtw_ea_multi(
         ub_arr = jnp.pad(
             ub_arr, ((0, 0), (0, k_pad - k)), constant_values=DEAD_LANE_UB
         )
-    if n_pad != n:
-        queries = jnp.pad(queries, ((0, 0), (0, n_pad - n)))
+    q_rows, q_spec, nrb = _query_rows(queries, row_block)
 
     ncb = k_pad // block_k
-    grid = (nq, ncb, n_pad // row_block)
+    grid = (nq, ncb, nrb)
+    tile = cand_tile_width(m, bw)
+    cb_w = cb_tile_width(m)
     # query-major flattened lane set: block row qi * ncb + ci
-    cand_flat = candidates.reshape(nq * k_pad, m)
-    cb_flat = cb_arr.reshape(nq * k_pad, m)
+    cand_flat = _pad_cols(candidates.reshape(nq * k_pad, m), tile)
+    cb_flat = _pad_cols(cb_arr.reshape(nq * k_pad, m), cb_w)
     ub_flat = ub_arr.reshape(nq * k_pad, 1)
 
     kernel = partial(
         _dtw_ea_kernel,
         n_rows=n,
+        m=m,
         window=window,
         row_block=row_block,
         band_width=bw,
         use_cb=use_cb,
         emit_info=with_info,
     )
-    lane_block = lambda qi, ci, ri: (qi * ncb + ci,)
-    lane_spec = pl.BlockSpec((block_k,), lane_block)
-    out_specs = [lane_spec]
-    out_shape = [jax.ShapeDtypeStruct((nq * k_pad,), jnp.float32)]
-    if with_info:
-        out_specs += [lane_spec, lane_spec]
-        out_shape += [
-            jax.ShapeDtypeStruct((nq * k_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((nq * k_pad,), jnp.int32),
-        ]
+    out_specs, out_shape = _per_lane_outputs(
+        block_k, ncb, nq * k_pad, with_info
+    )
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k, 1), lambda qi, ci, ri: (qi * ncb + ci, 0)),
-            pl.BlockSpec((1, row_block), lambda qi, ci, ri: (qi, ri)),
-            pl.BlockSpec((block_k, m), lambda qi, ci, ri: (qi * ncb + ci, 0)),
-            pl.BlockSpec((block_k, m), lambda qi, ci, ri: (qi * ncb + ci, 0)),
+            _lane_spec(block_k, ncb),
+            q_spec,
+            _lane_spec(block_k, ncb, tile),
+            _lane_spec(block_k, ncb, cb_w),
         ],
-        out_specs=out_specs if with_info else out_specs[0],
-        out_shape=out_shape if with_info else out_shape[0],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_k, bw), jnp.float32),
             pltpu.VMEM((block_k, 1), jnp.int32),
@@ -207,24 +310,17 @@ def dtw_ea_multi(
             pltpu.VMEM((block_k, 1), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
         ub_flat,
-        queries,
+        q_rows,
         cand_flat,
         cb_flat,
     )
-    if with_info:
-        d, rows, cells = out
-        return (
-            d.reshape(nq, k_pad)[:, :k],
-            rows.reshape(nq, k_pad)[:, :k],
-            cells.reshape(nq, k_pad)[:, :k],
-        )
-    return out.reshape(nq, k_pad)[:, :k]
+    return _per_lane(out, nq, k_pad, k, with_info)
 
 
 def dtw_ea(
@@ -236,7 +332,7 @@ def dtw_ea(
     band_width: int | None = None,
     block_k: int = 8,
     row_block: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
     with_info: bool = False,
 ):
     """Single-query batched EAPrunedDTW — ``dtw_ea_multi`` with ``Q = 1``.
@@ -246,8 +342,8 @@ def dtw_ea(
       candidates: ``(K, m)`` candidate windows (columns of the DP).
       ub: scalar upper bound shared by every lane, or a ``(K,)`` per-lane
         vector.
-      window, cb, band_width, block_k, row_block, with_info: as in
-        ``dtw_ea_multi`` (``cb`` is ``(K, m)`` here).
+      window, cb, band_width, block_k, row_block, interpret, with_info: as
+        in ``dtw_ea_multi`` (``cb`` is ``(K, m)`` here).
     Returns: ``(K,)`` float32 distances, ``+inf`` where abandoned; with
       ``with_info`` a ``(dists, rows, cells)`` tuple.
     """
@@ -274,7 +370,7 @@ def dtw_ea(
     jax.jit,
     static_argnames=(
         "window", "length", "use_cb", "band_width", "block_k", "row_block",
-        "interpret", "with_info", "ref_budget",
+        "interpret", "with_info",
     ),
 )
 def dtw_ea_multi_fused(
@@ -292,9 +388,8 @@ def dtw_ea_multi_fused(
     band_width: int | None = None,
     block_k: int = 8,
     row_block: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
     with_info: bool = False,
-    ref_budget: int | None = None,
 ):
     """Fused-gather ``dtw_ea_multi``: windows sliced + normalized in-kernel.
 
@@ -317,38 +412,20 @@ def dtw_ea_multi_fused(
         keeping flat-window output bit-identical to the retired slab).
       length: static candidate window length ``m``.
       u, low: ``(Q, m)`` query envelopes — required when ``use_cb``.
-      ref_budget: VMEM byte budget for the reference operand; a padded
-        reference above it stays in HBM and is DMA-streamed per lane
-        (default ``REF_VMEM_BYTES``).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     queries = jnp.asarray(queries, jnp.float32)
     starts = jnp.asarray(starts, jnp.int32)
     nq, n = queries.shape
     q_, k = starts.shape
     assert q_ == nq, (q_, nq)
     m = int(length)
-    window = int(min(window, m))
-
-    if band_width is None:
-        band_width = default_band_width(window, m) if n == m else m
-    bw = int(min(band_width, m))
-    full = min(2 * window + 1, m)
-    if bw < full:
-        raise ValueError(f"band_width {bw} < 2*window+1 = {full}")
-    if bw < m and n != m:
-        raise ValueError("banded dtw_ea requires equal lengths (n == m)")
+    window, bw = _band(window, n, m, band_width)
     if use_cb and (u is None or low is None):
         raise ValueError("use_cb requires the query envelopes (u, low)")
 
-    ref2 = _pad_ref_2d(ref)
-    n_ref_pad = ref2.shape[1]
-    budget = REF_VMEM_BYTES if ref_budget is None else int(ref_budget)
-    ref_in_vmem = n_ref_pad * 4 <= budget
+    ref2, ref_spec = _ref_operand(ref, m)
 
     k_pad = -(-k // block_k) * block_k
-    n_pad = -(-n // row_block) * row_block
     ub_arr = jnp.broadcast_to(jnp.asarray(ub, jnp.float32), (nq, k))
     mu_arr = jnp.asarray(mu, jnp.float32)
     sg_arr = jnp.asarray(sg, jnp.float32)
@@ -358,99 +435,67 @@ def dtw_ea_multi_fused(
         mu_arr = jnp.pad(mu_arr, pw)
         sg_arr = jnp.pad(sg_arr, pw, constant_values=1.0)
         ub_arr = jnp.pad(ub_arr, pw, constant_values=DEAD_LANE_UB)
-    if n_pad != n:
-        queries = jnp.pad(queries, ((0, 0), (0, n_pad - n)))
-    if u is None:
-        u_arr = jnp.zeros((nq, m), jnp.float32)
-        low_arr = jnp.zeros((nq, m), jnp.float32)
-    else:
-        u_arr = jnp.asarray(u, jnp.float32)
-        low_arr = jnp.asarray(low, jnp.float32)
+    q_rows, q_spec, nrb = _query_rows(queries, row_block)
+    u_arr, low_arr = _envelopes(u, low, nq, m)
 
     ncb = k_pad // block_k
-    grid = (nq, ncb, n_pad // row_block)
-    starts_flat = starts.reshape(nq * k_pad, 1)
-    mu_flat = mu_arr.reshape(nq * k_pad, 1)
-    sg_flat = sg_arr.reshape(nq * k_pad, 1)
-    ub_flat = ub_arr.reshape(nq * k_pad, 1)
+    grid = (nq, ncb, nrb)
+    starts_blk, starts_spec = _lane_starts(starts, block_k, ncb)
 
     kernel = partial(
         _dtw_ea_fused_kernel,
         n_rows=n,
+        m=m,
         window=window,
         row_block=row_block,
         band_width=bw,
         use_cb=use_cb,
         emit_info=with_info,
-        ref_in_vmem=ref_in_vmem,
     )
-    lane_block = lambda qi, ci, ri: (qi * ncb + ci,)
-    lane_spec = pl.BlockSpec((block_k,), lane_block)
-    lane2 = lambda: pl.BlockSpec(
-        (block_k, 1), lambda qi, ci, ri: (qi * ncb + ci, 0)
+    out_specs, out_shape = _per_lane_outputs(
+        block_k, ncb, nq * k_pad, with_info
     )
-    if ref_in_vmem:
-        ref_spec = pl.BlockSpec((1, n_ref_pad), lambda qi, ci, ri: (0, 0))
-    else:
-        ref_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    out_specs = [lane_spec]
-    out_shape = [jax.ShapeDtypeStruct((nq * k_pad,), jnp.float32)]
-    if with_info:
-        out_specs += [lane_spec, lane_spec]
-        out_shape += [
-            jax.ShapeDtypeStruct((nq * k_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((nq * k_pad,), jnp.int32),
-        ]
-    scratch = [
-        pltpu.VMEM((block_k, m), jnp.float32),    # normalized candidate tile
-        pltpu.VMEM((block_k, m), jnp.float32),    # in-kernel cb suffix
+    scratch = _gather_scratch(block_k, m, bw) + [
+        pltpu.VMEM((block_k, cb_tile_width(m)), jnp.float32),  # cb
         pltpu.VMEM((block_k, bw), jnp.float32),   # prev band
         pltpu.VMEM((block_k, 1), jnp.int32),      # next_start
         pltpu.VMEM((block_k, 2), jnp.int32),      # flags
         pltpu.VMEM((block_k, 1), jnp.int32),      # rows counter
         pltpu.VMEM((block_k, 1), jnp.int32),      # cells counter
         pltpu.SMEM((1,), jnp.int32),              # block done flag
+        _dma_sems(block_k),                       # window DMA semaphores
     ]
-    if not ref_in_vmem:
-        scratch.append(pltpu.SemaphoreType.DMA)   # window-slice DMA sem
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            lane2(),                                           # ub
-            pl.BlockSpec((1, row_block), lambda qi, ci, ri: (qi, ri)),
-            ref_spec,                                          # raw reference
-            lane2(),                                           # starts
-            lane2(),                                           # mu
-            lane2(),                                           # sigma
-            pl.BlockSpec((1, m), lambda qi, ci, ri: (qi, 0)),  # envelope u
-            pl.BlockSpec((1, m), lambda qi, ci, ri: (qi, 0)),  # envelope low
+            _lane_spec(block_k, ncb),  # ub
+            q_spec,
+            ref_spec,                  # raw reference
+            starts_spec,
+            _lane_spec(block_k, ncb),  # mu
+            _lane_spec(block_k, ncb),  # sigma
+            _query_spec(m),            # envelope u
+            _query_spec(m),            # envelope low
         ],
-        out_specs=out_specs if with_info else out_specs[0],
-        out_shape=out_shape if with_info else out_shape[0],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
-        ub_flat,
-        queries,
+        ub_arr.reshape(nq * k_pad, 1),
+        q_rows,
         ref2,
-        starts_flat,
-        mu_flat,
-        sg_flat,
+        starts_blk,
+        mu_arr.reshape(nq * k_pad, 1),
+        sg_arr.reshape(nq * k_pad, 1),
         u_arr,
         low_arr,
     )
-    if with_info:
-        d, rows, cells = out
-        return (
-            d.reshape(nq, k_pad)[:, :k],
-            rows.reshape(nq, k_pad)[:, :k],
-            cells.reshape(nq, k_pad)[:, :k],
-        )
-    return out.reshape(nq, k_pad)[:, :k]
+    return _per_lane(out, nq, k_pad, k, with_info)
 
 
 @partial(
@@ -472,7 +517,7 @@ def dtw_ea_persistent(
     band_width: int | None = None,
     block_k: int = 8,
     row_block: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Whole best-first EAPrunedDTW search in ONE launch per query set.
 
@@ -510,107 +555,85 @@ def dtw_ea_persistent(
       seed was never beaten), int32 count of candidate blocks that actually
       ran (the block-granular work metric; dispatches are 1 by construction).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     queries = jnp.asarray(queries, jnp.float32)
     candidates = jnp.asarray(candidates, jnp.float32)
     nq, n = queries.shape
     q_, k, m = candidates.shape
     assert q_ == nq, (q_, nq)
-    window = int(min(window, m))
-
-    if band_width is None:
-        band_width = default_band_width(window, m) if n == m else m
-    bw = int(min(band_width, m))
-    full = min(2 * window + 1, m)
-    if bw < full:
-        raise ValueError(f"band_width {bw} < 2*window+1 = {full}")
-    if bw < m and n != m:
-        raise ValueError("banded dtw_ea requires equal lengths (n == m)")
+    window, bw = _band(window, n, m, band_width)
     if use_cb and (u is None or low is None):
         raise ValueError("use_cb requires the query envelopes (u, low)")
 
-    n_pad = -(-n // row_block) * row_block
     lb_arr, starts_arr, candidates = pad_lanes_to_blocks(
         block_k, jnp.asarray(lb, jnp.float32),
         jnp.asarray(starts, jnp.int32), candidates,
     )
     k_pad = candidates.shape[1]
-    if n_pad != n:
-        queries = jnp.pad(queries, ((0, 0), (0, n_pad - n)))
-    if u is None:
-        u_arr = jnp.zeros((nq, m), jnp.float32)
-        low_arr = jnp.zeros((nq, m), jnp.float32)
-    else:
-        u_arr = jnp.asarray(u, jnp.float32)
-        low_arr = jnp.asarray(low, jnp.float32)
+    q_rows, q_spec, nrb = _query_rows(queries, row_block)
+    u_arr, low_arr = _envelopes(u, low, nq, m)
 
     ncb = k_pad // block_k
-    grid = (nq, ncb, n_pad // row_block)
-    cand_flat = candidates.reshape(nq * k_pad, m)
-    lb_flat = lb_arr.reshape(nq * k_pad, 1)
-    starts_flat = starts_arr.reshape(nq * k_pad, 1)
+    grid = (nq, ncb, nrb)
+    tile = cand_tile_width(m, bw)
+    cand_flat = _pad_cols(candidates.reshape(nq * k_pad, m), tile)
+    starts_blk, starts_spec = _lane_starts(starts_arr, block_k, ncb)
 
     kernel = partial(
         _dtw_ea_persistent_kernel,
         n_rows=n,
+        m=m,
         window=window,
         row_block=row_block,
         band_width=bw,
         use_cb=use_cb,
     )
-    lane2 = lambda shape: pl.BlockSpec(shape, lambda qi, ci, ri: (qi * ncb + ci, 0))
-    q_spec = pl.BlockSpec((1,), lambda qi, ci, ri: (qi,))
+    out_specs, out_shape = _per_query_outputs(nq)
     dist, idx, blocks = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # ub_init (Q,)
-            pl.BlockSpec((1, row_block), lambda qi, ci, ri: (qi, ri)),
-            lane2((block_k, m)),                              # candidates
-            lane2((block_k, 1)),                              # lb
-            lane2((block_k, 1)),                              # starts
-            pl.BlockSpec((1, m), lambda qi, ci, ri: (qi, 0)),  # envelope u
-            pl.BlockSpec((1, m), lambda qi, ci, ri: (qi, 0)),  # envelope low
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # ub_init (Q,)
+            q_spec,
+            _lane_spec(block_k, ncb, tile),         # candidates
+            _lane_spec(block_k, ncb),               # lb
+            starts_spec,
+            _query_spec(m),                         # envelope u
+            _query_spec(m),                         # envelope low
         ],
-        out_specs=[q_spec, q_spec, q_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq,), jnp.float32),
-            jax.ShapeDtypeStruct((nq,), jnp.int32),
-            jax.ShapeDtypeStruct((nq,), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_k, bw), jnp.float32),   # prev band
             pltpu.VMEM((block_k, 1), jnp.int32),      # next_start
             pltpu.VMEM((block_k, 2), jnp.int32),      # flags
             pltpu.VMEM((block_k, 1), jnp.float32),    # per-lane thresholds
-            pltpu.VMEM((block_k, m), jnp.float32),    # cb prologue slab
+            pltpu.VMEM((block_k, cb_tile_width(m)), jnp.float32),  # cb
             pltpu.SMEM((1,), jnp.int32),              # block done flag
             pltpu.SMEM((1,), jnp.float32),            # carried incumbent
             pltpu.SMEM((1,), jnp.int32),              # carried best start
             pltpu.SMEM((1,), jnp.int32),              # live-block counter
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(
         jnp.asarray(ub_init, jnp.float32),
-        queries,
+        q_rows,
         cand_flat,
-        lb_flat,
-        starts_flat,
+        lb_arr.reshape(nq * k_pad, 1),
+        starts_blk,
         u_arr,
         low_arr,
     )
-    return dist, idx, blocks
+    return dist[:, 0, 0], idx[:, 0, 0], blocks[:, 0, 0]
 
 
 @partial(
     jax.jit,
     static_argnames=(
         "window", "length", "use_cb", "band_width", "block_k", "row_block",
-        "interpret", "ref_budget",
+        "interpret",
     ),
 )
 def dtw_ea_persistent_fused(
@@ -629,8 +652,7 @@ def dtw_ea_persistent_fused(
     band_width: int | None = None,
     block_k: int = 8,
     row_block: int = 128,
-    interpret: bool | None = None,
-    ref_budget: int | None = None,
+    interpret: bool = False,
 ):
     """Fused-gather persistent sweep: the whole search, no window slab.
 
@@ -647,34 +669,17 @@ def dtw_ea_persistent_fused(
       ref: ``(N,)`` raw (sanitized) reference series.
       mu, sg: ``(Q, K)`` per-lane window mean and **pre-clamped** sigma.
       length: static candidate window length ``m``.
-      ref_budget: VMEM byte budget for the reference operand; above it the
-        reference stays in HBM and windows are DMA-streamed per lane
-        (default ``REF_VMEM_BYTES``).
 
     Returns: ``(best_dist, best_start, blocks)`` — as ``dtw_ea_persistent``.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     queries = jnp.asarray(queries, jnp.float32)
     nq, n = queries.shape
     m = int(length)
-    window = int(min(window, m))
-
-    if band_width is None:
-        band_width = default_band_width(window, m) if n == m else m
-    bw = int(min(band_width, m))
-    full = min(2 * window + 1, m)
-    if bw < full:
-        raise ValueError(f"band_width {bw} < 2*window+1 = {full}")
-    if bw < m and n != m:
-        raise ValueError("banded dtw_ea requires equal lengths (n == m)")
+    window, bw = _band(window, n, m, band_width)
     if use_cb and (u is None or low is None):
         raise ValueError("use_cb requires the query envelopes (u, low)")
 
-    ref2 = _pad_ref_2d(ref)
-    n_ref_pad = ref2.shape[1]
-    budget = REF_VMEM_BYTES if ref_budget is None else int(ref_budget)
-    ref_in_vmem = n_ref_pad * 4 <= budget
+    ref2, ref_spec = _ref_operand(ref, m)
 
     lb_arr = jnp.asarray(lb, jnp.float32)
     starts_arr = jnp.asarray(starts, jnp.int32)
@@ -688,90 +693,69 @@ def dtw_ea_persistent_fused(
         starts_arr = jnp.pad(starts_arr, pw)  # start 0 is always in range
         mu_arr = jnp.pad(mu_arr, pw)
         sg_arr = jnp.pad(sg_arr, pw, constant_values=1.0)
-    n_pad = -(-n // row_block) * row_block
-    if n_pad != n:
-        queries = jnp.pad(queries, ((0, 0), (0, n_pad - n)))
-    if u is None:
-        u_arr = jnp.zeros((nq, m), jnp.float32)
-        low_arr = jnp.zeros((nq, m), jnp.float32)
-    else:
-        u_arr = jnp.asarray(u, jnp.float32)
-        low_arr = jnp.asarray(low, jnp.float32)
+    q_rows, q_spec, nrb = _query_rows(queries, row_block)
+    u_arr, low_arr = _envelopes(u, low, nq, m)
 
     ncb = k_pad // block_k
-    grid = (nq, ncb, n_pad // row_block)
-    lb_flat = lb_arr.reshape(nq * k_pad, 1)
-    starts_flat = starts_arr.reshape(nq * k_pad, 1)
-    mu_flat = mu_arr.reshape(nq * k_pad, 1)
-    sg_flat = sg_arr.reshape(nq * k_pad, 1)
+    grid = (nq, ncb, nrb)
+    starts_blk, starts_spec = _lane_starts(starts_arr, block_k, ncb)
 
     kernel = partial(
         _dtw_ea_persistent_kernel,
         n_rows=n,
+        m=m,
         window=window,
         row_block=row_block,
         band_width=bw,
         use_cb=use_cb,
         fused=True,
-        ref_in_vmem=ref_in_vmem,
     )
-    lane2 = lambda shape: pl.BlockSpec(shape, lambda qi, ci, ri: (qi * ncb + ci, 0))
-    q_spec = pl.BlockSpec((1,), lambda qi, ci, ri: (qi,))
-    if ref_in_vmem:
-        ref_spec = pl.BlockSpec((1, n_ref_pad), lambda qi, ci, ri: (0, 0))
-    else:
-        ref_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    scratch = [
-        pltpu.VMEM((block_k, m), jnp.float32),    # normalized candidate tile
+    out_specs, out_shape = _per_query_outputs(nq)
+    scratch = _gather_scratch(block_k, m, bw) + [
         pltpu.VMEM((block_k, bw), jnp.float32),   # prev band
         pltpu.VMEM((block_k, 1), jnp.int32),      # next_start
         pltpu.VMEM((block_k, 2), jnp.int32),      # flags
         pltpu.VMEM((block_k, 1), jnp.float32),    # per-lane thresholds
-        pltpu.VMEM((block_k, m), jnp.float32),    # cb prologue slab
+        pltpu.VMEM((block_k, cb_tile_width(m)), jnp.float32),  # cb
         pltpu.SMEM((1,), jnp.int32),              # block done flag
         pltpu.SMEM((1,), jnp.float32),            # carried incumbent
         pltpu.SMEM((1,), jnp.int32),              # carried best start
         pltpu.SMEM((1,), jnp.int32),              # live-block counter
+        _dma_sems(block_k),                       # window DMA semaphores
     ]
-    if not ref_in_vmem:
-        scratch.append(pltpu.SemaphoreType.DMA)   # window-slice DMA sem
     dist, idx, blocks = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # ub_init (Q,)
-            pl.BlockSpec((1, row_block), lambda qi, ci, ri: (qi, ri)),
-            ref_spec,                                         # raw reference
-            lane2((block_k, 1)),                              # lb
-            lane2((block_k, 1)),                              # starts
-            lane2((block_k, 1)),                              # mu
-            lane2((block_k, 1)),                              # sigma
-            pl.BlockSpec((1, m), lambda qi, ci, ri: (qi, 0)),  # envelope u
-            pl.BlockSpec((1, m), lambda qi, ci, ri: (qi, 0)),  # envelope low
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # ub_init (Q,)
+            q_spec,
+            ref_spec,                               # raw reference
+            _lane_spec(block_k, ncb),               # lb
+            starts_spec,
+            _lane_spec(block_k, ncb),               # mu
+            _lane_spec(block_k, ncb),               # sigma
+            _query_spec(m),                         # envelope u
+            _query_spec(m),                         # envelope low
         ],
-        out_specs=[q_spec, q_spec, q_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq,), jnp.float32),
-            jax.ShapeDtypeStruct((nq,), jnp.int32),
-            jax.ShapeDtypeStruct((nq,), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(
         jnp.asarray(ub_init, jnp.float32),
-        queries,
+        q_rows,
         ref2,
-        lb_flat,
-        starts_flat,
-        mu_flat,
-        sg_flat,
+        lb_arr.reshape(nq * k_pad, 1),
+        starts_blk,
+        mu_arr.reshape(nq * k_pad, 1),
+        sg_arr.reshape(nq * k_pad, 1),
         u_arr,
         low_arr,
     )
-    return dist, idx, blocks
+    return dist[:, 0, 0], idx[:, 0, 0], blocks[:, 0, 0]
 
 
 @partial(
@@ -787,7 +771,7 @@ def lb_keogh_all_windows(
     qends: jax.Array,
     length: int,
     chunk: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """LB_Kim + LB_Keogh for every z-normalized window of ``ref``.
 
@@ -797,10 +781,9 @@ def lb_keogh_all_windows(
       mu, sigma: per-window stats ``(N_win,)`` (from search.znorm).
       upper, lower: query envelope ``(length,)``.
       qends: ``(2,)`` first/last value of the z-normalized query (LB_Kim).
+      interpret: run the kernel body in Python instead of through Mosaic.
     Returns: ``(N_win,)`` lower bounds (max of Kim and Keogh).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     ref = jnp.asarray(ref, jnp.float32)
     n = ref.shape[0]
     n_win = n - length + 1
@@ -825,7 +808,7 @@ def lb_keogh_all_windows(
         ],
         out_specs=pl.BlockSpec((chunk,), lambda ci: (ci,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.synthetic import DATASETS, make_dataset, make_queries
+from repro.launch.compile_cache import enable_compile_cache
 from repro.search import make_distributed_search, subsequence_search
 from repro.search.subsequence import VARIANTS
 
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--distributed", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     ref = jnp.asarray(make_dataset(args.dataset, args.ref_len, args.seed), jnp.float32)
     queries = make_queries(args.dataset, args.n_queries, args.query_len, args.seed)

@@ -177,12 +177,11 @@ def moe_ep(p: dict, x: jax.Array, cfg, mesh, batch_axes: tuple, tp_axis: str = "
         P(tp_axis, None, None),
         None if shared is None else jax.tree.map(lambda _: P(None, None), shared),
     )
-    from repro.core.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(ba, None, None), P()),
+        check_vma=False,
     )
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)

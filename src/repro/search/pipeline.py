@@ -86,7 +86,6 @@ from repro.core.common import (
     norm_window_slice,
     pad_lanes_to_blocks,
 )
-from repro.core.compat import shard_map as _shard_map
 from repro.core.dtw import dtw
 from repro.core.lower_bounds import (
     cascade_keogh_cumulative,
@@ -1144,13 +1143,16 @@ def make_sharded_search(
         else:
             q_ok = jnp.ones_like(valid)
 
-        shard = _shard_map(
+        # check_vma=False: the per-device round loop mixes device-varying
+        # and replicated values.
+        shard = jax.shard_map(
             local_search,
             mesh=mesh,
             in_specs=(
                 spec_rep, spec_rep, spec_sharded, spec_sharded, spec_sharded,
             ),
             out_specs=(spec_rep, spec_rep, spec_rep, spec_rep),
+            check_vma=False,
         )
         return shard(ref, queries_n, starts, valid, q_ok)
 

@@ -122,13 +122,22 @@ def test_batch_dispatch_backends_agree():
 
 def test_resolve_backend_rules():
     assert resolve_backend("jax") == "jax"
-    assert resolve_backend("pallas") == "pallas"
     assert resolve_backend("pallas_interpret") == "pallas_interpret"
-    assert resolve_backend("auto") in ("pallas", "jax")
+    on_tpu = jax.default_backend() == "tpu"
+    assert resolve_backend("auto") == ("pallas" if on_tpu else "jax")
     with pytest.raises(ValueError):
         resolve_backend("mosaic")
     for b in ("jax", "pallas"):
         assert b in BACKENDS
+
+
+def test_pallas_backend_raises_off_tpu():
+    """``pallas`` never falls back to the interpreter in silence."""
+    if jax.default_backend() == "tpu":
+        assert resolve_backend("pallas") == "pallas"
+        return
+    with pytest.raises(ValueError, match="pallas_interpret"):
+        resolve_backend("pallas")
 
 
 def test_env_var_override_subprocess():

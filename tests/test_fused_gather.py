@@ -13,9 +13,8 @@ Also pinned here:
   * the slab-budget regression — a persistent sweep completes under a
     ``slab_budget`` that the O(K·l) slab form cannot satisfy (it raises at
     trace time instead of allocating), and its results equal host rounds;
-  * the HBM reference tier — a ``ref_budget`` too small for VMEM residency
-    switches the fused kernels to per-lane DMA streaming with bit-identical
-    results;
+  * the fused kernels' HBM reference — windows fetched by per-lane DMA
+    equal the slab kernels' pre-gathered windows bit for bit;
   * the golden pipeline scenario's slab arm — the frontends' ``"slab"``
     comparison mode still matches the fused default they now run by.
 """
@@ -201,8 +200,9 @@ def test_slab_budget_persistent_regression(backend):
     )
 
 
-def test_hbm_tier_ref_budget_parity():
-    """A reference over the VMEM budget DMA-streams with identical results."""
+def test_hbm_reference_kernels_match_slab_kernels():
+    """Fused kernels DMA windows from the HBM reference; the slab kernels
+    take them pre-gathered. Distances and winners are identical."""
     from repro.kernels import ops
 
     ref, queries = _series()
@@ -211,23 +211,24 @@ def test_hbm_tier_ref_budget_parity():
     starts = jnp.asarray([[0, 60, 120, 180, 240, 300, 350]] * 2, jnp.int32)
     mu_l = mu[starts]                      # ops layer takes per-lane stats
     sg_l = clamp_sigma(sigma)[starts]      # pre-clamped by contract
+    cand = jnp.stack([
+        gather_norm_windows(ref, s, LENGTH, mu, sigma) for s in starts
+    ])
     ub = jnp.full((2, 7), BIG, jnp.float32)
-    kw = dict(window=WINDOW, length=LENGTH, block_k=4, interpret=True)
-    d_vmem = ops.dtw_ea_multi_fused(qn, ref, starts, mu_l, sg_l, ub, **kw)
-    d_hbm = ops.dtw_ea_multi_fused(
-        qn, ref, starts, mu_l, sg_l, ub, ref_budget=256, **kw
+    kw = dict(window=WINDOW, block_k=4, interpret=True)
+    d_fused = ops.dtw_ea_multi_fused(
+        qn, ref, starts, mu_l, sg_l, ub, length=LENGTH, **kw
     )
-    assert np.array_equal(np.asarray(d_vmem), np.asarray(d_hbm))
+    d_slab = ops.dtw_ea_multi(qn, cand, ub, **kw)
+    assert np.array_equal(np.asarray(d_fused), np.asarray(d_slab))
 
     lb = jnp.asarray([[0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7]] * 2, jnp.float32)
     ub0 = jnp.full((2,), BIG, jnp.float32)
-    p_vmem = ops.dtw_ea_persistent_fused(
-        qn, ref, lb, starts, mu_l, sg_l, ub0, **kw
+    p_fused = ops.dtw_ea_persistent_fused(
+        qn, ref, lb, starts, mu_l, sg_l, ub0, length=LENGTH, **kw
     )
-    p_hbm = ops.dtw_ea_persistent_fused(
-        qn, ref, lb, starts, mu_l, sg_l, ub0, ref_budget=256, **kw
-    )
-    for a, b in zip(p_vmem, p_hbm):
+    p_slab = ops.dtw_ea_persistent(qn, cand, lb, starts, ub0, **kw)
+    for a, b in zip(p_fused, p_slab):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 

@@ -32,7 +32,10 @@ def test_dtw_ea_kernel_sweep(n, k, w, block_k, row_block):
     exact = np.asarray(dtw_exact_ref(q, c, w))
     for ub in (np.median(exact), exact.max() * 1.01, exact.min() * 0.9):
         got = np.asarray(
-            dtw_ea(q, c, float(ub), window=w, block_k=block_k, row_block=row_block)
+            dtw_ea(
+                q, c, float(ub), window=w, block_k=block_k,
+                row_block=row_block, interpret=True,
+            )
         )
         ref = np.asarray(dtw_ea_ref(q, c, float(ub), window=w))
         assert np.array_equal(np.isfinite(got), np.isfinite(ref)), (got, ref)
@@ -48,7 +51,9 @@ def test_dtw_ea_kernel_cb():
     cb = jnp.flip(jnp.cumsum(jnp.flip(terms, -1), -1), -1)
     exact = np.asarray(dtw_exact_ref(q, c, w))
     ub = float(np.median(exact))
-    got = np.asarray(dtw_ea(q, c, ub, window=w, cb=cb, block_k=8, row_block=32))
+    got = np.asarray(
+        dtw_ea(q, c, ub, window=w, cb=cb, block_k=8, row_block=32, interpret=True)
+    )
     ref = np.asarray(dtw_ea_ref(q, c, ub, window=w, cb=cb))
     assert np.array_equal(np.isfinite(got), np.isfinite(ref))
     fin = np.isfinite(ref)
@@ -60,7 +65,9 @@ def test_dtw_ea_kernel_value_vs_exact():
     n, k, w = 64, 12, 8
     q, c = _mk(n, k, seed=11)
     exact = np.asarray(dtw_exact_ref(q, c, w))
-    got = np.asarray(dtw_ea(q, c, float(exact.max() * 1.01), window=w))
+    got = np.asarray(
+        dtw_ea(q, c, float(exact.max() * 1.01), window=w, interpret=True)
+    )
     np.testing.assert_allclose(got, exact, rtol=1e-5)
 
 
@@ -77,7 +84,10 @@ def test_lb_kernel_sweep(n_ref, length, w, chunk):
     u, low = envelope(q, w)
     qe = jnp.asarray([q[0], q[-1]], jnp.float32)
     got = np.asarray(
-        lb_keogh_all_windows(ref, mu, sg, u, low, qe, length=length, chunk=chunk)
+        lb_keogh_all_windows(
+            ref, mu, sg, u, low, qe, length=length, chunk=chunk,
+            interpret=True,
+        )
     )
     want = np.asarray(lb_all_windows_ref(ref, q, mu, sg, length, w))
     np.testing.assert_allclose(got, want, rtol=3e-4, atol=1e-4)
@@ -93,7 +103,11 @@ def test_lb_kernel_is_lower_bound():
     mu, sg = window_stats(ref, length)
     u, low = envelope(q, w)
     qe = jnp.asarray([q[0], q[-1]], jnp.float32)
-    lbs = np.asarray(lb_keogh_all_windows(ref, mu, sg, u, low, qe, length=length))
+    lbs = np.asarray(
+        lb_keogh_all_windows(
+            ref, mu, sg, u, low, qe, length=length, interpret=True
+        )
+    )
     qn = np.asarray(q)
     for s in range(0, n_ref - length + 1, 37):
         wnd = np.asarray(ref[s : s + length])
