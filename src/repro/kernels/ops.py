@@ -293,6 +293,7 @@ def dtw_ea_multi(
     )
     out = pl.pallas_call(
         kernel,
+        name="dtw_ea_multi",
         grid=grid,
         in_specs=[
             _lane_spec(block_k, ncb),
@@ -467,6 +468,7 @@ def dtw_ea_multi_fused(
     ]
     out = pl.pallas_call(
         kernel,
+        name="dtw_ea_multi_fused",
         grid=grid,
         in_specs=[
             _lane_spec(block_k, ncb),  # ub
@@ -590,6 +592,7 @@ def dtw_ea_persistent(
     out_specs, out_shape = _per_query_outputs(nq)
     dist, idx, blocks = pl.pallas_call(
         kernel,
+        name="dtw_ea_persistent",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # ub_init (Q,)
@@ -725,6 +728,7 @@ def dtw_ea_persistent_fused(
     ]
     dist, idx, blocks = pl.pallas_call(
         kernel,
+        name="dtw_ea_persistent_fused",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # ub_init (Q,)
@@ -797,6 +801,7 @@ def lb_keogh_all_windows(
     kernel = partial(_lb_kernel, length=length, chunk=chunk, n_win=n_win)
     out = pl.pallas_call(
         kernel,
+        name="lb_keogh_all_windows",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # query endpoints (2,)

@@ -38,6 +38,8 @@ class DistSearchResult(NamedTuple):
     best_dist: jax.Array
     rounds: jax.Array
     quarantined: jax.Array  # windows excluded by the non-finite quarantine
+    lanes: jax.Array      # candidate windows submitted, summed over shards
+    lb_pruned: jax.Array  # windows never evaluated: n_win - lanes
 
 
 def make_distributed_search(
@@ -83,12 +85,12 @@ def make_distributed_search(
     sharded = make_sharded_search(mesh, axis_names, plan)
 
     def search_fn(ref: jax.Array, query: jax.Array) -> DistSearchResult:
-        best_d, best_s, rounds, n_quar = sharded(
+        best_d, best_s, rounds, n_quar, lanes, pruned = sharded(
             jnp.asarray(ref), jnp.asarray(query)[None]
         )
         return DistSearchResult(
             best_start=best_s[0], best_dist=best_d[0], rounds=rounds,
-            quarantined=n_quar,
+            quarantined=n_quar, lanes=lanes[0], lb_pruned=pruned[0],
         )
 
     return search_fn

@@ -113,6 +113,8 @@ class DistMultiSearchResult(NamedTuple):
     quarantined: jax.Array  # windows excluded by the non-finite quarantine
     #   (scalar: windows are query-independent; psum over shards == the
     #   single-device count)
+    lanes: jax.Array       # (Q,) candidate windows submitted, all shards
+    lb_pruned: jax.Array   # (Q,) windows never evaluated: n_win - lanes
 
 
 def multi_query_search(
@@ -272,10 +274,12 @@ def make_distributed_multi_search(
     sharded = make_sharded_search(mesh, axis_names, plan)
 
     def search_fn(ref: jax.Array, queries: jax.Array) -> DistMultiSearchResult:
-        best_d, best_s, rounds, n_quar = sharded(ref, queries)
+        best_d, best_s, rounds, n_quar, lanes, pruned = sharded(
+            ref, queries
+        )
         return DistMultiSearchResult(
             best_start=best_s, best_dist=best_d, rounds=rounds,
-            quarantined=n_quar,
+            quarantined=n_quar, lanes=lanes, lb_pruned=pruned,
         )
 
     return search_fn

@@ -28,6 +28,11 @@ Stages
                                 vectorized lax.pmin incumbent reconcile
                                 (make_sharded_search / ShardedExecutor)
 
+Each stage traces under a ``jax.named_scope`` — ``dtw.prepare``,
+``dtw.cascade`` (the argsort in ``dtw.cascade/dtw.sort``), ``dtw.execute``
+and, for the mesh collectives inside it, ``dtw.reconcile`` — so every
+frontend's compiled ops name their stage in their HLO ``op_name``.
+
 Incumbent state (``ub``/``best``, strict-improvement fold, dead-lane
 sentinel) and quarantine counters live in ``search.incumbents``.
 
@@ -61,6 +66,7 @@ Frontend ↔ executor binding (public signatures unchanged):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -120,6 +126,19 @@ VARIANTS = ("full", "pruned", "eapruned", "eapruned_nolb")
 MULTI_VARIANTS = ("eapruned", "eapruned_nolb")
 ROUND_DRIVERS = ("host", "persistent")
 GATHER_MODES = ("fused", "slab")
+
+
+def _stage(name: str):
+    """Trace the decorated stage under ``jax.named_scope(name)``: its ops
+    carry ``name`` in their ``op_name`` (``jit(f)/dtw.cascade/while/...``).
+    Metadata only; the compiled program is unchanged."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -277,6 +296,7 @@ class PreparedQueries(NamedTuple):
     low: jax.Array  # (Q, l) lower LB_Keogh envelope
 
 
+@_stage("dtw.prepare")
 def prepare_ref(plan: SearchPlan, ref, valid=None) -> PreparedRef:
     """Window stats + the one §2.6 quarantine prepass.
 
@@ -304,6 +324,7 @@ def prepare_ref(plan: SearchPlan, ref, valid=None) -> PreparedRef:
     return PreparedRef(ref=ref, mu=mu, sigma=sigma, valid=valid, n_quar=n_quar)
 
 
+@_stage("dtw.prepare")
 def prepare_queries(plan: SearchPlan, queries) -> PreparedQueries:
     """Z-normalize the workload's queries and build their envelopes."""
     qn = znorm(jnp.asarray(queries)[:, : plan.length])
@@ -315,6 +336,14 @@ def prepare_queries(plan: SearchPlan, queries) -> PreparedQueries:
 # cascade — the one LB gate
 # ---------------------------------------------------------------------------
 
+def _sort_bounds(lbs) -> tuple[jax.Array, jax.Array]:
+    """Best-first order of each row of ``lbs`` and the bounds in it."""
+    with jax.named_scope("dtw.sort"):
+        order = jnp.argsort(lbs, axis=1)
+        return order, jnp.take_along_axis(lbs, order, axis=1)
+
+
+@_stage("dtw.cascade")
 def cascade(plan: SearchPlan, prep: PreparedRef, qn) -> tuple[jax.Array, jax.Array]:
     """Per-query lower bounds → best-first candidate order.
 
@@ -337,19 +366,18 @@ def cascade(plan: SearchPlan, prep: PreparedRef, qn) -> tuple[jax.Array, jax.Arr
         )(qn)                                          # (Q, n_win)
         if prep.valid is not None:
             lbs = jnp.where(prep.valid[None, :], lbs, jnp.inf)
-        order = jnp.argsort(lbs, axis=1)
-        return order, jnp.take_along_axis(lbs, order, axis=1)
+        return _sort_bounds(lbs)
     if prep.valid is not None:
         lbs = jnp.broadcast_to(
             jnp.where(prep.valid, 0.0, jnp.inf).astype(qn.dtype),
             (nq, n_win),
         )
-        order = jnp.argsort(lbs, axis=1)
-        return order, jnp.take_along_axis(lbs, order, axis=1)
+        return _sort_bounds(lbs)
     order = jnp.broadcast_to(jnp.arange(n_win), (nq, n_win))
     return order, jnp.zeros((nq, n_win), qn.dtype)
 
 
+@_stage("dtw.cascade")
 def local_cascade(
     plan: SearchPlan, prep: PreparedRef, qn, starts, valid
 ) -> jax.Array:
@@ -511,6 +539,7 @@ def warm_prepass(
     return state, pre, rows_pre, cells_pre
 
 
+@_stage("dtw.execute")
 def run_host_rounds(
     plan: SearchPlan,
     prep: PreparedRef,
@@ -667,6 +696,7 @@ def run_host_rounds(
 # persistent-sweep executor core
 # ---------------------------------------------------------------------------
 
+@_stage("dtw.execute")
 def run_persistent(
     plan: SearchPlan,
     prep: PreparedRef,
@@ -778,12 +808,27 @@ def _baseline_search_impl(ref, query, plan: SearchPlan, with_info):
     Returns scalar-field ``(IncumbentState, SearchStats, n_quar)`` shaped
     like Q=1 (length-1 arrays).
     """
-    query_n = znorm(jnp.asarray(query)[: plan.length])
+    with jax.named_scope("dtw.prepare"):
+        query_n = znorm(jnp.asarray(query)[: plan.length])
+        u, low = envelope(query_n, plan.window)
     prep = prepare_ref(plan, ref)
-    n_win = prep.mu.shape[0]
     order, lb_sorted = cascade(plan, prep, query_n[None])
+    state, stats = _baseline_execute(
+        plan, prep, query_n, u, low, order, lb_sorted, with_info
+    )
+    return state, stats, prep.n_quar
+
+
+@_stage("dtw.execute")
+def _baseline_execute(
+    plan: SearchPlan, prep: PreparedRef, query_n, u, low, order, lb_sorted,
+    with_info,
+) -> tuple[IncumbentState, SearchStats]:
+    """The baselines' scalar-incumbent sweep over the best-first ``(1,
+    n_win)`` order: a round loop, or one persistent launch / ``block_sweep``.
+    """
     order, lb_sorted = order[0], lb_sorted[0]
-    u, low = envelope(query_n, plan.window)
+    n_win = prep.mu.shape[0]
     use_lb, use_cb = plan.use_lb, plan.use_cb
     knobs = plan.knobs()
 
@@ -856,7 +901,7 @@ def _baseline_search_impl(ref, query, plan: SearchPlan, with_info):
             rows=no_info[None],
             cells=no_info[None],
         )
-        return state, stats, prep.n_quar
+        return state, stats
 
     batch = plan.batch
     n_rounds = -(-n_win // batch)
@@ -933,7 +978,7 @@ def _baseline_search_impl(ref, query, plan: SearchPlan, with_info):
         rows=(st.rows if with_info else no_info)[None],
         cells=(st.cells if with_info else no_info)[None],
     )
-    return state, stats, prep.n_quar
+    return state, stats
 
 
 # ---------------------------------------------------------------------------
@@ -972,8 +1017,9 @@ def make_sharded_search(
     """Build the jitted sharded search program for a mesh config.
 
     Returns ``search_fn(ref, queries) -> (best_dist (Q,), best_start (Q,),
-    rounds, n_quar)``. Work items are (query, candidate-range) pairs:
-    candidate window starts are sharded contiguously across the mesh axes
+    rounds, n_quar, lanes (Q,), lb_pruned (Q,))``. Work items are (query,
+    candidate-range) pairs: candidate window starts are sharded
+    contiguously across the mesh axes
     (each device owns a slice of every query's windows), queries ride in
     the lane dimension of the per-device multi-query batch, and after every
     round the per-query incumbent vector is reconciled with one vectorized
@@ -985,7 +1031,10 @@ def make_sharded_search(
     are condemned on the shard that owns them (``+inf`` LB → dead-lane
     sentinel, query-independent), counts ``psum``-reduce to the
     single-device total, and the sanitized reference keeps the shared
-    prefix sums finite for survivors.
+    prefix sums finite for survivors. ``lanes`` and ``lb_pruned`` count
+    windows as :func:`run_host_rounds` does, summed over the shards; the
+    windows that pad the reference to the mesh size count in neither, so
+    ``lanes + lb_pruned`` is the number of windows.
     """
     n_shards = 1
     for a in axis_names:
@@ -996,152 +1045,159 @@ def make_sharded_search(
 
     def local_search(ref, queries_n, starts, valid, q_ok):
         nq = queries_n.shape[0]
+        n_win = ref.shape[0] - plan.length + 1
 
-        def psum_all(x):
-            for a in axis_names:
-                x = jax.lax.psum(x, a)
-            return x
+        def all_axes(collective, x):
+            with jax.named_scope("dtw.reconcile"):
+                for a in axis_names:
+                    x = collective(x, a)
+                return x
 
-        # Quarantine accounting before the mask folds into ``valid``: each
-        # shard counts its own real (non-padding) condemned windows, and
-        # the psum reconciles them into the global count every shard
-        # reports.
-        n_quar = psum_all(
-            jnp.sum(jnp.logical_and(valid, ~q_ok)).astype(jnp.int32)
-        )
-        valid = jnp.logical_and(valid, q_ok)
-        mu, sigma = window_stats(ref, plan.length)
+        psum_all = partial(all_axes, jax.lax.psum)
+        pmin_all = partial(all_axes, jax.lax.pmin)
+        pmax_all = partial(all_axes, jax.lax.pmax)
+
+        with jax.named_scope("dtw.prepare"):
+            # Quarantine accounting before the mask folds into ``valid``:
+            # each shard counts its own real (non-padding) condemned
+            # windows; the psum after the rounds reconciles them into the
+            # global count every shard reports.
+            quar_local = jnp.sum(
+                jnp.logical_and(valid, ~q_ok)
+            ).astype(jnp.int32)
+            # Mesh padding is no window: its +inf bounds sort it behind
+            # every real window (the argsort is stable and padding holds
+            # the shard's last starts), so a query's first ``n_real``
+            # sorted lanes are its real windows.
+            n_real = jnp.sum(valid).astype(jnp.int32)
+            valid = jnp.logical_and(valid, q_ok)
+            mu, sigma = window_stats(ref, plan.length)
+            u, low = jax.vmap(envelope, in_axes=(0, None))(
+                queries_n, plan.window
+            )
         prep = PreparedRef(
-            ref=ref, mu=mu, sigma=sigma, valid=None, n_quar=n_quar
+            ref=ref, mu=mu, sigma=sigma, valid=None, n_quar=quar_local
         )
         lbs = local_cascade(plan, prep, queries_n, starts, valid)
-        order = jnp.argsort(lbs, axis=1)
-        starts_o = jnp.take_along_axis(
-            jnp.broadcast_to(starts, lbs.shape), order, axis=1
-        )
-        lb_o = jnp.take_along_axis(lbs, order, axis=1)
-        n_local = starts.shape[0]
-        n_rounds = -(-n_local // batch)
-        pad = n_rounds * batch - n_local
-        starts_p = jnp.concatenate(
-            [starts_o, jnp.zeros((nq, pad), starts_o.dtype)], axis=1
-        )
-        lb_p = jnp.concatenate(
-            [lb_o, jnp.full((nq, pad), jnp.inf, lb_o.dtype)], axis=1
-        )
-        u, low = jax.vmap(envelope, in_axes=(0, None))(
-            queries_n, plan.window
-        )
-
-        def pmin_all(x):
-            for a in axis_names:
-                x = jax.lax.pmin(x, a)
-            return x
-
-        def pmax_all(x):
-            for a in axis_names:
-                x = jax.lax.pmax(x, a)
-            return x
-
-        slice_round, peek_lb = _round_slicers(batch)
-        if plan.gather != "fused":
-            _ensure_slab_budget(plan, nq * batch, "make_sharded_search")
-
-        class St(NamedTuple):
-            r: jax.Array        # (Q,) local per-query round pointer
-            ub: jax.Array       # (Q,) globally reconciled incumbents
-            loc: IncumbentState  # local best (start, dist per lane fold)
-            go: jax.Array       # global continue flag
-
-        def cond(st: St) -> jax.Array:
-            return st.go
-
-        def body(st: St) -> St:
-            s = slice_round(starts_p, st.r)            # (Q, batch)
-            lb = slice_round(lb_p, st.r)
-            head = peek_lb(lb_p, st.r)
-            local_more = jnp.logical_and(st.r < n_rounds, head < st.ub)
-            # Dead-lane sentinel for finished (query, range) items and for
-            # lanes whose own lower bound already reaches the incumbent
-            # (lane-level LB gating, as in the host round driver).
-            lane_live = jnp.logical_and(
-                local_more[:, None], lb < st.ub[:, None]
+        with jax.named_scope("dtw.cascade"):
+            order, lb_o = _sort_bounds(lbs)
+            starts_o = jnp.take_along_axis(
+                jnp.broadcast_to(starts, lbs.shape), order, axis=1
             )
-            ub_lanes = jnp.where(
-                lane_live,
-                jnp.broadcast_to(st.ub[:, None], (nq, batch)),
-                DEAD_LANE_UB,
+        with jax.named_scope("dtw.execute"):
+            n_local = starts.shape[0]
+            n_rounds = -(-n_local // batch)
+            pad = n_rounds * batch - n_local
+            starts_p = jnp.concatenate(
+                [starts_o, jnp.zeros((nq, pad), starts_o.dtype)], axis=1
             )
-            if plan.gather == "fused":
-                d = ea_pruned_dtw_multi_batch_fused(
-                    queries_n, ref, s, ub_lanes, window=plan.window,
-                    mu=mu, sigma=sigma, envelopes=(u, low),
-                    band_width=plan.band_width, **plan.knobs(),
+            lb_p = jnp.concatenate(
+                [lb_o, jnp.full((nq, pad), jnp.inf, lb_o.dtype)], axis=1
+            )
+            slice_round, peek_lb = _round_slicers(batch)
+            if plan.gather != "fused":
+                _ensure_slab_budget(plan, nq * batch, "make_sharded_search")
+
+            class St(NamedTuple):
+                r: jax.Array        # (Q,) local per-query round pointer
+                ub: jax.Array       # (Q,) globally reconciled incumbents
+                loc: IncumbentState  # local best (start, dist per lane fold)
+                go: jax.Array       # global continue flag
+
+            def cond(st: St) -> jax.Array:
+                return st.go
+
+            def body(st: St) -> St:
+                s = slice_round(starts_p, st.r)            # (Q, batch)
+                lb = slice_round(lb_p, st.r)
+                head = peek_lb(lb_p, st.r)
+                local_more = jnp.logical_and(st.r < n_rounds, head < st.ub)
+                # Dead-lane sentinel for finished (query, range) items and for
+                # lanes whose own lower bound already reaches the incumbent
+                # (lane-level LB gating, as in the host round driver).
+                lane_live = jnp.logical_and(
+                    local_more[:, None], lb < st.ub[:, None]
                 )
-            else:
-                cand = jax.vmap(
-                    lambda ss: gather_norm_windows(
-                        ref, ss, plan.length, mu, sigma
+                ub_lanes = jnp.where(
+                    lane_live,
+                    jnp.broadcast_to(st.ub[:, None], (nq, batch)),
+                    DEAD_LANE_UB,
+                )
+                if plan.gather == "fused":
+                    d = ea_pruned_dtw_multi_batch_fused(
+                        queries_n, ref, s, ub_lanes, window=plan.window,
+                        mu=mu, sigma=sigma, envelopes=(u, low),
+                        band_width=plan.band_width, **plan.knobs(),
                     )
-                )(s)
-                cb = jax.vmap(cascade_keogh_cumulative)(cand, u, low)
-                d = ea_pruned_dtw_multi_batch(
-                    queries_n, cand, ub_lanes, window=plan.window,
-                    band_width=plan.band_width, cb=cb, **plan.knobs(),
+                else:
+                    cand = jax.vmap(
+                        lambda ss: gather_norm_windows(
+                            ref, ss, plan.length, mu, sigma
+                        )
+                    )(s)
+                    cb = jax.vmap(cascade_keogh_cumulative)(cand, u, low)
+                    d = ea_pruned_dtw_multi_batch(
+                        queries_n, cand, ub_lanes, window=plan.window,
+                        band_width=plan.band_width, cb=cb, **plan.knobs(),
+                    )
+                d = jnp.where(jnp.isfinite(lb), d, jnp.inf)  # padding lanes
+                d = jnp.where(local_more[:, None], d, jnp.inf)
+                # Local fold keeps this shard's best achieved pair; the global
+                # incumbent only needs the bound, reconciled by one vectorized
+                # pmin per round.
+                loc, _ = fold_min(st.loc, s, d)
+                ub = pmin_all(jnp.minimum(st.ub, loc.ub))
+                r = st.r + local_more.astype(st.r.dtype)
+                nxt = peek_lb(lb_p, jnp.minimum(r, n_rounds - 1))
+                local_next = jnp.logical_and(r < n_rounds, nxt < ub)
+                return St(
+                    r=r, ub=ub, loc=loc, go=pmax_all(jnp.any(local_next)),
                 )
-            d = jnp.where(jnp.isfinite(lb), d, jnp.inf)  # padding lanes
-            d = jnp.where(local_more[:, None], d, jnp.inf)
-            # Local fold keeps this shard's best achieved pair; the global
-            # incumbent only needs the bound, reconciled by one vectorized
-            # pmin per round.
-            loc, _ = fold_min(st.loc, s, d)
-            ub = pmin_all(jnp.minimum(st.ub, loc.ub))
-            r = st.r + local_more.astype(st.r.dtype)
-            nxt = peek_lb(lb_p, jnp.minimum(r, n_rounds - 1))
-            local_next = jnp.logical_and(r < n_rounds, nxt < ub)
-            return St(
-                r=r, ub=ub, loc=loc, go=pmax_all(jnp.any(local_next)),
-            )
 
-        go0 = pmax_all(jnp.asarray(True))
-        st0 = St(
-            r=jnp.zeros((nq,), jnp.int32),
-            ub=jnp.full((nq,), BIG, queries_n.dtype),
-            loc=IncumbentState(
+            go0 = pmax_all(jnp.asarray(True))
+            st0 = St(
+                r=jnp.zeros((nq,), jnp.int32),
                 ub=jnp.full((nq,), BIG, queries_n.dtype),
-                best=jnp.full((nq,), -1, starts.dtype),
-            ),
-            go=go0,
-        )
-        st = jax.lax.while_loop(cond, body, st0)
-        # Per-query global argmin: vectorized lexicographic
-        # (distance, start).
-        g_min = pmin_all(st.loc.ub)                    # (Q,)
-        is_best = jnp.isclose(st.loc.ub, g_min)
-        cand_start = jnp.where(
-            is_best, st.loc.best, jnp.iinfo(jnp.int32).max
-        )
-        g_start = pmin_all(cand_start.astype(jnp.int32))
-        return g_min, g_start, pmax_all(jnp.max(st.r)), n_quar
+                loc=IncumbentState(
+                    ub=jnp.full((nq,), BIG, queries_n.dtype),
+                    best=jnp.full((nq,), -1, starts.dtype),
+                ),
+                go=go0,
+            )
+            st = jax.lax.while_loop(cond, body, st0)
+            # Per-query global argmin: vectorized lexicographic
+            # (distance, start).
+            g_min = pmin_all(st.loc.ub)                    # (Q,)
+            is_best = jnp.isclose(st.loc.ub, g_min)
+            cand_start = jnp.where(
+                is_best, st.loc.best, jnp.iinfo(jnp.int32).max
+            )
+            g_start = pmin_all(cand_start.astype(jnp.int32))
+            # Lanes as ``run_host_rounds`` counts them, real windows only.
+            lanes = psum_all(jnp.minimum(st.r * batch, n_real))
+            return (g_min, g_start, pmax_all(jnp.max(st.r)),
+                    psum_all(quar_local), lanes, n_win - lanes)
 
     @jax.jit
     def search_fn(ref: jax.Array, queries: jax.Array):
-        ref = jnp.asarray(ref)
-        queries_n = znorm(jnp.asarray(queries)[:, : plan.length])
-        n_win = ref.shape[0] - plan.length + 1
-        per = -(-n_win // n_shards)
-        total = per * n_shards
-        starts = jnp.arange(total, dtype=jnp.int32)
-        valid = starts < n_win
-        starts = jnp.minimum(starts, n_win - 1)
-        if plan.quarantine:
-            # Mask on the raw series, sanitize before replication so shared
-            # prefix sums stay finite for the surviving windows (§2.6).
-            finite_ok = window_finite_mask(ref, plan.length)
-            ref = sanitize_series(ref)
-            q_ok = finite_ok[starts]
-        else:
-            q_ok = jnp.ones_like(valid)
+        with jax.named_scope("dtw.prepare"):
+            ref = jnp.asarray(ref)
+            queries_n = znorm(jnp.asarray(queries)[:, : plan.length])
+            n_win = ref.shape[0] - plan.length + 1
+            per = -(-n_win // n_shards)
+            total = per * n_shards
+            starts = jnp.arange(total, dtype=jnp.int32)
+            valid = starts < n_win
+            starts = jnp.minimum(starts, n_win - 1)
+            if plan.quarantine:
+                # Mask on the raw series, sanitize before replication so
+                # shared prefix sums stay finite for the surviving windows
+                # (§2.6).
+                finite_ok = window_finite_mask(ref, plan.length)
+                ref = sanitize_series(ref)
+                q_ok = finite_ok[starts]
+            else:
+                q_ok = jnp.ones_like(valid)
 
         # check_vma=False: the per-device round loop mixes device-varying
         # and replicated values.
@@ -1151,7 +1207,7 @@ def make_sharded_search(
             in_specs=(
                 spec_rep, spec_rep, spec_sharded, spec_sharded, spec_sharded,
             ),
-            out_specs=(spec_rep, spec_rep, spec_rep, spec_rep),
+            out_specs=(spec_rep,) * 6,
             check_vma=False,
         )
         return shard(ref, queries_n, starts, valid, q_ok)
@@ -1262,20 +1318,21 @@ class ShardedExecutor:
         self, plan: SearchPlan, state: IncumbentState, lo: int, hi: int
     ) -> RangeResult:
         seg = self.ref[lo : hi + plan.length - 1]
-        best_d, best_s, rounds, n_quar = self._fn(plan)(seg, self.queries)
+        best_d, best_s, rounds, n_quar, lanes, lb_pruned = self._fn(plan)(
+            seg, self.queries
+        )
         improved = best_d < jnp.asarray(state.ub, best_d.dtype)
         merged = IncumbentState(
             ub=jnp.where(improved, best_d, state.ub),
             best=jnp.where(improved, best_s + lo, state.best),
         )
         nq = self.queries.shape[0]
-        n_win = hi - lo
         no_info = jnp.full((nq,), -1)
         return RangeResult(
             state=merged,
             stats=SearchStats(
                 rounds=jnp.broadcast_to(rounds, (nq,)),
-                lanes=no_info, lb_pruned=no_info, rows=no_info,
+                lanes=lanes, lb_pruned=lb_pruned, rows=no_info,
                 cells=no_info,
             ),
             quarantined=n_quar,
