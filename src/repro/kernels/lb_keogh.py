@@ -6,6 +6,12 @@ the window start: for fixed ``i``, the contribution of offset ``i`` to all
 perfect VPU lanes — normalized per window and clamped against the scalar
 envelope values ``U[i]``/``L[i]``. ``length`` iterations of ``(chunk,)``-wide
 FMAs replace the CPU suite's per-candidate loop.
+
+The search does not run this kernel: its cascade
+(``search/cascade.py:cascade_lower_bounds``) is the same offset-major pass
+in plain jnp, one code path for every backend. The kernel stays a tested
+alternative in interpret mode; the v5e's compiler refuses it, since the
+offset slices ``ref_ref[pl.ds(c0 + i, chunk)]`` are unaligned vector loads.
 """
 from __future__ import annotations
 

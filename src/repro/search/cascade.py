@@ -5,8 +5,12 @@ the TPU-native replacement for the UCR suite's per-candidate cascade. The
 output is a best-first candidate ordering plus per-window lower bounds, which
 stage 2 (batched EAPrunedDTW, search/subsequence.py) consumes.
 
-Chunked over windows so the materialized ``(chunk, l)`` window matrix stays
-within a fixed memory budget regardless of reference length.
+The pass runs offset-major: for a query offset ``j`` the term of every
+window is the one unit-stride slice ``ref[j : j + n_win]``, normalized by
+the per-window ``(mu, sigma)`` tables and clamped against the scalars
+``U[j]``/``L[j]``. ``length`` such steps accumulate into an ``(n_win,)``
+vector, so no ``(windows, length)`` block is ever built and the work is
+``length`` wide vector ops rather than one slice per window.
 """
 from __future__ import annotations
 
@@ -16,8 +20,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.common import norm_window_slice
-from repro.core.lower_bounds import envelope, lb_keogh, lb_kim_fl
+from repro.core.common import clamp_sigma
+from repro.core.lower_bounds import _lb_keogh_terms, envelope
+
+# Offsets per loop step: enough for XLA to fuse a few slices into one pass
+# over the tables, few enough that l=1024 compiles quickly.
+_UNROLL = 8
 
 
 class CascadeOut(NamedTuple):
@@ -26,7 +34,7 @@ class CascadeOut(NamedTuple):
     n_windows: int
 
 
-@partial(jax.jit, static_argnames=("length", "window", "use_kim", "use_keogh", "chunk"))
+@partial(jax.jit, static_argnames=("length", "window", "use_kim", "use_keogh"))
 def cascade_lower_bounds(
     ref: jax.Array,
     query_n: jax.Array,
@@ -36,37 +44,41 @@ def cascade_lower_bounds(
     window: int,
     use_kim: bool = True,
     use_keogh: bool = True,
-    chunk: int = 4096,
 ) -> jax.Array:
     """Lower bound for every candidate window start. Returns ``(N,)``.
 
     ``query_n`` must already be z-normalized. When both bounds are enabled the
-    result is their max (both are valid DTW lower bounds).
+    result is their max (both are valid DTW lower bounds). Each normalized
+    value equals ``norm_window_slice``'s; only the order of the sum differs.
     """
     n_win = ref.shape[0] - length + 1
-    u, low = envelope(query_n, window)
+    sg = clamp_sigma(sigma)
 
-    n_chunks = -(-n_win // chunk)
-    pad_total = n_chunks * chunk
+    def norm(x):
+        return (x - mu) / sg
 
-    def one_chunk(c0: jax.Array) -> jax.Array:
-        starts = c0 + jnp.arange(chunk)
-        valid = starts < n_win
-        safe = jnp.minimum(starts, n_win - 1)
-        cand = norm_window_slice(ref, safe, length, mu, sigma)
-        lb = jnp.zeros((chunk,), cand.dtype)
-        if use_kim:
-            lb = jnp.maximum(lb, lb_kim_fl(query_n, cand))
-        if use_keogh:
-            lb = jnp.maximum(lb, lb_keogh(cand, u, low))
-        return jnp.where(valid, lb, jnp.inf)
+    lb = jnp.zeros((n_win,), ref.dtype)
+    if use_kim:
+        kim = ((norm(ref[:n_win]) - query_n[0]) ** 2
+               + (norm(ref[length - 1:]) - query_n[length - 1]) ** 2)
+        lb = jnp.maximum(lb, kim)
+    if use_keogh:
+        u, low = envelope(query_n, window)
 
-    chunk_starts = jnp.arange(n_chunks) * chunk
-    lbs = jax.lax.map(one_chunk, chunk_starts).reshape(pad_total)
-    return lbs[:n_win]
+        def offset(j, acc):
+            v = norm(jax.lax.dynamic_slice(ref, (j,), (n_win,)))
+            return acc + _lb_keogh_terms(v, u[j], low[j])
+
+        keogh = jax.lax.fori_loop(
+            0, length, offset,
+            jnp.zeros((n_win,), ref.dtype),
+            unroll=min(_UNROLL, length),
+        )
+        lb = jnp.maximum(lb, keogh)
+    return lb
 
 
-@partial(jax.jit, static_argnames=("length", "window", "use_kim", "use_keogh", "chunk"))
+@partial(jax.jit, static_argnames=("length", "window", "use_kim", "use_keogh"))
 def cascade(
     ref: jax.Array,
     query_n: jax.Array,
@@ -76,14 +88,13 @@ def cascade(
     window: int,
     use_kim: bool = True,
     use_keogh: bool = True,
-    chunk: int = 4096,
 ) -> tuple[jax.Array, jax.Array]:
     """Best-first ordering of window starts by lower bound.
 
     Returns ``(order, lb_sorted)``; both ``(N,)`` with N = #windows.
     """
     lbs = cascade_lower_bounds(
-        ref, query_n, mu, sigma, length, window, use_kim, use_keogh, chunk
+        ref, query_n, mu, sigma, length, window, use_kim, use_keogh
     )
     order = jnp.argsort(lbs)
     return order, lbs[order]

@@ -160,7 +160,7 @@ class SearchPlan:
     variant: str = "eapruned"
     batch: int = 64
     band_width: int | None = None
-    chunk: int = 4096
+    chunk: int = 4096         # local_cascade's gathered-window block
     backend: str = "jax"
     rows_per_step: int = 1
     block_k: int = 8
@@ -360,8 +360,7 @@ def cascade(plan: SearchPlan, prep: PreparedRef, qn) -> tuple[jax.Array, jax.Arr
     if plan.use_lb:
         lbs = jax.vmap(
             lambda q: cascade_lower_bounds(
-                prep.ref, q, prep.mu, prep.sigma, plan.length, plan.window,
-                chunk=plan.chunk,
+                prep.ref, q, prep.mu, prep.sigma, plan.length, plan.window
             )
         )(qn)                                          # (Q, n_win)
         if prep.valid is not None:

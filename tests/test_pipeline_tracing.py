@@ -23,39 +23,51 @@ from repro.search import pipeline
 
 STAGES = ("dtw.prepare", "dtw.cascade", "dtw.execute")
 SCOPES = STAGES + ("dtw.reconcile", "dtw.sort")
-_OP = re.compile(r'%\S+ = \S+ ([a-z-]+)\(.*op_name="([^"]*)"')
+_OP = re.compile(r'%\S+ = (\S+) ([a-z-]+)\(.*op_name="([^"]*)"')
+N_REF, LENGTH = 500, 32
+N_WIN = N_REF - LENGTH + 1
+
+
+def hlo_shaped_ops(hlo: str) -> list[tuple[str, str, str]]:
+    """``(shape, opcode, op_name)`` of every instruction with an op_name."""
+    return [m.groups() for m in map(_OP.search, hlo.splitlines()) if m]
 
 
 def hlo_ops(hlo: str) -> list[tuple[str, str]]:
     """``(opcode, op_name)`` of every instruction that has an op_name."""
-    return [m.groups() for m in map(_OP.search, hlo.splitlines()) if m]
+    return [(op, name) for _, op, name in hlo_shaped_ops(hlo)]
 
 
 def _scenario():
     rng = np.random.default_rng(5)
-    ref = jnp.asarray(np.cumsum(rng.normal(size=500)), jnp.float32)
-    return ref, ref[None, 100:132]
+    ref = jnp.asarray(np.cumsum(rng.normal(size=N_REF)), jnp.float32)
+    return ref, ref[None, 100:100 + LENGTH]
 
 
-def _compiled_ops(kind: str) -> list[tuple[str, str]]:
+def _compiled_hlo(kind: str) -> str:
     ref, q = _scenario()
     if kind == "baseline":
-        plan = pipeline.make_plan(length=32, window=3, batch=16, chunk=64,
+        plan = pipeline.make_plan(length=LENGTH, window=3, batch=16, chunk=64,
                                   variant="full", backend="jax")
         low = pipeline._baseline_search_impl.lower(
             ref, q[0], plan=plan, with_info=False)
     else:
-        plan = pipeline.make_plan(length=32, window=3, batch=16, chunk=64,
+        plan = pipeline.make_plan(length=LENGTH, window=3, batch=16, chunk=64,
                                   rounds=kind, backend="jax")
         low = pipeline._offline_search_impl.lower(
             ref, q, jnp.full((1,), jnp.inf, jnp.float32), plan=plan,
             with_info=False)
-    return hlo_ops(low.compile().as_text())
+    return low.compile().as_text()
+
+
+def _compiled_ops(kind: str) -> list[tuple[str, str]]:
+    return hlo_ops(_compiled_hlo(kind))
 
 
 @pytest.mark.parametrize("kind", ["host", "persistent", "baseline"])
 def test_compiled_search_carries_the_stage_scopes(kind):
-    ops = _compiled_ops(kind)
+    shaped = hlo_shaped_ops(_compiled_hlo(kind))
+    ops = [(op, name) for _, op, name in shaped]
     for stage in STAGES:
         assert any(stage in name.split("/") for _, name in ops), stage
     # Only the named scopes, and the outermost is always a stage.
@@ -63,10 +75,15 @@ def test_compiled_search_carries_the_stage_scopes(kind):
         mine = [s for s in name.split("/") if s.startswith("dtw.")]
         assert set(mine) <= set(SCOPES), name
         assert not mine or mine[0] in STAGES, name
-    # The cascade's window gather, inside its chunked loop.
-    assert any(op in ("gather", "dynamic-slice")
-               and "dtw.cascade/" in name and "/while/body/" in name
-               for op, name in ops)
+    # The cascade slices no single window out of the reference: its loop
+    # over query offsets takes one slice across every window start.
+    cascade = [(shape, op, name) for shape, op, name in shaped
+               if "dtw.cascade/" in name
+               and op in ("gather", "dynamic-slice", "slice")]
+    assert not [c for c in cascade
+                if re.match(rf"f32\[(\d+,)*{LENGTH}\]", c[0])], cascade
+    assert any(shape.startswith(f"f32[{N_WIN}]") and op == "dynamic-slice"
+               and "/while/body/" in name for shape, op, name in cascade)
     # The argsort sits in its sub-scope.
     assert any("dtw.cascade/dtw.sort/" in name for _, name in ops)
 
