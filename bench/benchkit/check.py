@@ -60,15 +60,16 @@ class Reference:
         self.n_win = len(ref) - length + 1
         self._d64: dict[tuple[int, int], float] = {}
 
-    def nearest(self, indices, *, dtype="float32", device=None
+    def nearest(self, indices, *, dtype="float32", devices=None
                 ) -> dict[int, Expected]:
         """Nearest windows of the pool queries ``indices``, searched in
-        ``dtype``. ``"bfloat16"`` is the precision control: the same
-        search in the precision below the configuration's float32."""
+        ``dtype`` on ``devices``. ``"bfloat16"`` is the precision control:
+        the same search in the precision below the configuration's
+        float32."""
         indices = sorted(set(indices))
         starts, dists, runner = reference.search(
             self.ref, self.pool[indices], self.length, self.window,
-            dtype=dtype, device=device)
+            dtype=dtype, devices=devices)
         return {i: Expected(int(s), float(d), float(r))
                 for i, s, d, r in zip(indices, starts, dists, runner)}
 

@@ -1,10 +1,10 @@
 """One run of one cell: set-up, a closed-loop window, the check, the metrics.
 
 The system under test is the program under ``src/``: a cell's ``layout``
-names its entry in ``ENTRIES``; ``single`` drives
-``repro.search.subsequence_search`` on one chip. The deployment's shape
-(``ref_len``, ``query_len``, ``window_ratio``) comes from the
-configuration file; every tuning knob from the program's own
+names its entry, the file ``bench/entries/<layout>.py`` (``spec``);
+``single`` drives ``repro.search.subsequence_search`` on one chip. The
+deployment's shape (``ref_len``, ``query_len``, ``window_ratio``) comes
+from the configuration file; every tuning knob from the program's own
 ``repro.configs.dtw_search.CONFIG``, so a PR that retunes the program is
 measured as its users get it.
 
@@ -95,31 +95,6 @@ def knobs(cell: spec.Cell) -> dict:
     )
 
 
-class OneChipEntry:
-    """``subsequence_search`` over a reference resident on one chip."""
-
-    def __init__(self, cell, ref, devs):
-        from repro.configs.dtw_search import CONFIG
-        from repro.search import subsequence_search
-
-        self._search = subsequence_search
-        self._knobs = dict(knobs(cell), variant=CONFIG.variant,
-                           rounds=CONFIG.rounds, gather=CONFIG.gather)
-        self.ref = jax.device_put(ref, devs[0])
-
-    def dispatch(self, query):
-        return self._search(self.ref, query, **self._knobs)
-
-    @staticmethod
-    def fetch(res) -> tuple:
-        s, d, r, p = jax.device_get(
-            (res.best_start, res.best_dist, res.rounds, res.lb_pruned))
-        return int(s), float(d), int(r), int(p)
-
-
-ENTRIES = {"single": OneChipEntry}
-
-
 class CompileCounter:
     """Counts JAX trace, lowering and compile events while ``on``."""
 
@@ -202,6 +177,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     """
     t_start = time.perf_counter() if t_start is None else t_start
     cell = spec.load_cell(name, root)
+    Entry = spec.entry_class(cell.config["layout"], root)
     devs = require_chip(cell.chips)
     t_chip = time.perf_counter()
     from repro.core.backend import resolve_backend
@@ -209,10 +185,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     if devs[0].platform == "tpu" and resolve_backend() != "pallas":
         raise NoChip(f"the DTW backend resolved to {resolve_backend()!r}, "
                      "not 'pallas'")
-    layout = cell.config["layout"]
     kn = knobs(cell)
     wl = traffic.build(cell.config, cell.traffic, seed)
-    entry = ENTRIES[layout](cell, wl.ref, devs)
+    entry = Entry(cell, wl.ref, devs)
     t_data = time.perf_counter()
     # Set-up: this cell's own program, once; with the persistent cache
     # warm it only loads.
@@ -252,7 +227,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                for r in records]
     if not answers:
         raise RuntimeError("no query was answered in the window")
-    expected = refc.nearest([a.pool_index for a in answers], device=devs[0])
+    t_ref = time.perf_counter()
+    expected = refc.nearest([a.pool_index for a in answers], devices=devs)
+    reference_s = time.perf_counter() - t_ref
     numbers = refc.compare(answers, expected)
     limits = cell.config["limits"]
     correct = not failed and check.verdict(numbers, limits)
@@ -273,7 +250,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     info = dict(queries=len(records), window_s=window_s, setup_s=setup_s,
                 setup_to_chip_s=t_chip - t_start,
                 setup_data_s=t_data - t_chip, setup_warm_s=t_warm - t_data,
-                distinct_queries=len(expected),
+                distinct_queries=len(expected), reference_s=reference_s,
                 **refc.diagnostics(answers, expected))
     if trace:
         info.update(traced_queries=len(traced),

@@ -1,16 +1,26 @@
-"""Find a cell's configuration, traffic mix and metric readers by name.
+"""Find a cell's configuration, traffic mix, entry and metric readers by name.
 
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric lives in a file of its own, found from the names in
-``BENCHMARK.json``:
+Everything that belongs to one configuration, one traffic mix, one entry
+or one per-layer metric lives in a file of its own, found from the names
+in ``BENCHMARK.json`` and the configuration file:
 
 * a configuration: the ``file`` its ``configs`` entry names (JSON);
 * a traffic mix: ``bench/traffic/<traffic>.json``;
+* an entry: ``bench/entries/<layout>.py``, ``layout`` being the
+  configuration's, a module with ``class Entry``:
+
+  - ``Entry(cell, ref, devs)``: ``devs`` are the cell's ``chips``
+    devices; puts the resident reference where the layout needs it;
+  - ``dispatch(query)``: the program's device result, not waited for;
+  - ``fetch(result)``: ``(best_start, best_dist, rounds, lb_pruned)`` as
+    Python numbers;
+  - dropping the entry (``del entry``) frees the program's state;
+
 * a metric: ``bench/metrics/<name>.py``, a module with
   ``read(run) -> float | None`` (``None``: nothing to read in this run).
 
-Adding a cell, a mix or a metric therefore adds files and entries and
-edits none. ``root`` is the checkout's root directory.
+Adding a cell, a mix, a layout or a metric therefore adds files and
+entries and edits none. ``root`` is the checkout's root directory.
 """
 from __future__ import annotations
 
@@ -49,6 +59,18 @@ def metric_path(root: Path, name: str) -> Path:
     return Path(root) / "bench" / "metrics" / f"{name}.py"
 
 
+def entry_path(root: Path, layout: str) -> Path:
+    return Path(root) / "bench" / "entries" / f"{layout}.py"
+
+
+def _module(path: Path, name: str):
+    """The module in file ``path``, loaded anew under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def load_cell(name: str, root: Path = ROOT) -> Cell:
     """The cell ``name`` with its configuration and traffic files read."""
     root = Path(root)
@@ -71,13 +93,19 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 
 def metric_module(name: str, root: Path = ROOT):
     """The reader module of metric ``name``."""
-    path = metric_path(root, name)
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return _module(metric_path(root, name), f"bench_metric_{name}")
 
 
 def metric_reader(name: str, root: Path = ROOT):
     """The ``read(run) -> float | None`` function of metric ``name``."""
     return metric_module(name, root).read
+
+
+def entry_class(layout: str, root: Path = ROOT):
+    """The ``Entry`` class of ``layout``; raises ``FileNotFoundError``
+    naming the path looked for where it has no file."""
+    path = entry_path(root, layout)
+    if not path.is_file():
+        raise FileNotFoundError(f"no entry for layout {layout!r}: {path} "
+                                "does not exist")
+    return _module(path, f"bench_entry_{layout}").Entry

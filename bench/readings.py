@@ -45,6 +45,7 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
     cell = spec.load_cell(args.workload, ROOT)
+    Entry = spec.entry_class(cell.config["layout"], ROOT)
     try:
         devs = harness.require_chip(cell.chips)
     except harness.NoChip as e:
@@ -55,7 +56,7 @@ def main(argv=None) -> int:
     for n, seed in enumerate(int(s) for s in args.seeds.split(",")):
         wl = traffic.build(cell.config, {**cell.traffic, "data_seed": seed},
                            seed)
-        entry = harness.ENTRIES[cell.config["layout"]](cell, wl.ref, devs)
+        entry = Entry(cell, wl.ref, devs)
         answers = []
         for p in pool:
             s, d, _, _ = entry.fetch(jax.block_until_ready(
@@ -65,13 +66,13 @@ def main(argv=None) -> int:
         gc.collect()
         refc = check.Reference(wl.ref, wl.pool, kn["length"], kn["window"],
                                wl.offsets)
-        exp = refc.nearest(pool, device=devs[0])
+        exp = refc.nearest(pool, devices=devs)
         line = {"seed": seed, "side": "program",
                 **refc.compare(answers, exp),
                 **refc.diagnostics(answers, exp)}
         print(json.dumps(line), flush=True)
         if n < args.control:
-            ctl = refc.nearest(pool, dtype="bfloat16", device=devs[0])
+            ctl = refc.nearest(pool, dtype="bfloat16", devices=devs)
             line = {"seed": seed, "side": "control_bfloat16",
                     **refc.compare(check.control_answers(ctl, pool), exp)}
             print(json.dumps(line), flush=True)

@@ -21,14 +21,15 @@ TINY = "tiny-planted"
 
 
 def tiny_root(tmp_path: Path, *, ref_len=4096, query_len=128,
-              pool=4) -> Path:
+              pool=4, layout="single") -> Path:
     """A checkout-like copy of ``BENCHMARK.json`` and ``bench/`` with one
     more cell, ``tiny-planted``, added by new files and entries only."""
     shutil.copy(REPO / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
     shutil.copytree(REPO / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     cfg = json.loads((REPO / "bench/configs/ucr-ecg-l128.json").read_text())
-    cfg.update(name="tiny", ref_len=ref_len, query_len=query_len)
+    cfg.update(name="tiny", ref_len=ref_len, query_len=query_len,
+               layout=layout)
     (tmp_path / "bench/configs/tiny.json").write_text(json.dumps(cfg))
     mix = {"name": "planted-tiny", "data_seed": 2**31 + 11, "pool": pool,
            "plant_noise": 0.05}

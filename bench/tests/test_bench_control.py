@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import json
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -36,6 +38,65 @@ def test_device_reference_matches_float64_brute_force(dataset, seed):
         assert s == bs
         assert d == pytest.approx(bd, rel=1e-5)
         assert r >= d
+
+
+# The fori carry of 64 windows at w=3: 8 queries x (2w + 2) x 4 bytes each.
+# 369 windows in blocks of at most 64 make 6 blocks of 62 over two device
+# slots, and 8 blocks of 47 over four devices.
+CARRY_64 = 64 * 8 * (2 * 3 + 2) * 4
+
+
+def blocked_against_whole(devs, size):
+    """Checks the search in blocks of ``size`` windows dealt to ``devs``
+    against one block. Integer samples make the window statistics exact,
+    so two copies of a window tie exactly: query 0 at 10 and ``2 * size``
+    (the first window of a block), query 1 at ``size - 1`` (the last of
+    one) and ``3 * size`` (the first of another)."""
+    rng = np.random.default_rng(2**31 + 7)
+    ref = rng.integers(-8, 9, 400).astype(np.float32)
+    for a, b in ((10, 2 * size), (size - 1, 3 * size)):
+        ref[b : b + 32] = ref[a : a + 32]
+    queries = np.stack([ref[10:42], ref[size - 1 : size + 31]])
+    carry = reference.CARRY_BYTES
+    with x32():
+        whole = reference.search(ref, queries, 32, 3)
+        try:
+            reference.CARRY_BYTES = CARRY_64
+            blocked = reference.search(ref, queries, 32, 3, devices=devs)
+        finally:
+            reference.CARRY_BYTES = carry
+    assert all(w.tobytes() == b.tobytes() for w, b in zip(whole, blocked))
+    starts, dists, runner = blocked
+    assert list(starts) == [10, size - 1]
+    assert np.array_equal(runner, dists)  # the tie is exact
+
+
+_FOUR_DEVICES = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import sys; sys.path.insert(0, "bench/tests")
+import jax
+from test_bench_control import blocked_against_whole
+devs = jax.devices()
+assert len(set(devs)) == 4, devs
+blocked_against_whole(devs, 47)
+print("BLOCKS OK")
+"""
+
+
+@pytest.mark.parametrize("devices", ["one dealt twice", "four"])
+def test_window_blocks_give_the_unblocked_answers_bit_for_bit(devices):
+    if devices == "four":  # separate devices need a process of their own
+        out = subprocess.run([sys.executable, "-c", _FOUR_DEVICES],
+                             capture_output=True, text=True, timeout=300,
+                             cwd=REPO)
+        assert out.returncode == 0, out.stderr[-3000:]
+        assert "BLOCKS OK" in out.stdout
+        return
+    import jax
+
+    blocked_against_whole(jax.devices()[:1] * 2, 62)
 
 
 @pytest.mark.parametrize("seed", [2**31 + 1, 2**31 + 2, 2**31 + 3])

@@ -8,7 +8,7 @@ import re
 import numpy as np
 import pytest
 
-from bench_fixtures import REPO, TINY, tiny_root
+from bench_fixtures import REPO, TINY, run_off_chip, tiny_root
 from benchkit import spec, traffic
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -111,3 +111,51 @@ def test_added_config_and_mix_are_found_without_editing_a_file(tmp_path):
     assert wl.ref.shape == (4096,) and wl.pool.shape == (4, 128)
     with pytest.raises(KeyError):
         spec.load_cell("no-such-cell", root)
+
+
+COUNTING_ENTRY = '''"""``single``'s entry, counting its dispatches."""
+from pathlib import Path
+
+from benchkit import spec
+
+Single = spec.entry_class("single", Path(__file__).resolve().parents[2])
+
+
+class Entry(Single):
+    dispatches = 0
+
+    def dispatch(self, query):
+        type(self).dispatches += 1
+        return super().dispatch(query)
+'''
+
+
+def test_added_layout_is_found_without_editing_a_file(tmp_path, monkeypatch):
+    before = {k: v for k, v in _digest(REPO).items()
+              if not k.startswith("bench/tests/")}
+    root = tiny_root(tmp_path, ref_len=1024, pool=2, layout="tiny_counting")
+    spec.entry_path(root, "tiny_counting").write_text(COUNTING_ENTRY)
+    after = _digest(root)
+    assert all(after[k] == v for k, v in before.items())  # nothing edited
+    assert set(after) - set(before) == {"bench/configs/tiny.json",
+                                        "bench/traffic/planted-tiny.json",
+                                        "bench/entries/tiny_counting.py"}
+    loaded, entry_class = {}, spec.entry_class
+
+    def recorded(layout, root):
+        loaded[layout] = entry_class(layout, root)
+        return loaded[layout]
+
+    monkeypatch.setattr(spec, "entry_class", recorded)
+    res = run_off_chip(monkeypatch, root, seconds=0.3)
+    assert res["correct"] is True, res
+    counting = loaded["tiny_counting"]
+    assert counting.__module__ == "bench_entry_tiny_counting"
+    assert counting.dispatches > res["attempted"] >= 1  # and the warm-up
+
+
+def test_unknown_layout_names_the_path_looked_for(tmp_path, monkeypatch):
+    root = tiny_root(tmp_path, layout="no_such_layout")
+    path = str(spec.entry_path(root, "no_such_layout"))
+    with pytest.raises(FileNotFoundError, match=re.escape(path)):
+        run_off_chip(monkeypatch, root)
