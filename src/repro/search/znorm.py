@@ -7,14 +7,24 @@ materialized lazily only for the candidates that survive the LB cascade.
 
 Two forms of the stats table:
 
-  * ``window_stats`` — offline: one prefix-sum pass over the whole reference.
+  * ``window_stats`` — offline: one pass of prefix sums, taken within blocks
+    of ``length`` samples, over the whole reference.
   * ``append_window_stats`` — appendable: given the ``length - 1`` tail of
     samples already seen and a new chunk, produce the stats of exactly the
     windows that *become valid* with that chunk (including the windows
     straddling the tail/chunk boundary) in O(tail + chunk) work. A stream of
-    appends therefore builds the same table as one offline pass over the
-    concatenated series — without ever touching more than the boundary
-    context. ``search/streaming.py`` drives this per ingest.
+    appends therefore builds the table of one offline pass over the
+    concatenated series, to float rounding (the blocks start at the
+    context's first sample, not the series') — without ever touching more
+    than the boundary context. ``search/streaming.py`` drives this per
+    ingest.
+
+Precision: a window's sums never come from the difference of two sums over
+more than ``length`` samples, so their float32 error does not grow with the
+series. A running prefix over the whole series does: on the ECG analogue at
+1,000,000 samples (l=128) it gave sigma a relative error of 1.1e-2 at
+worst and 1.1e-4 at the median (XLA:CPU), where the blocked sums give
+4e-6 and 6e-8.
 
 Sigma handling (flat-segment audit): ``window_stats`` returns the *raw*
 standard deviation — zero for a constant window — because pruning statistics
@@ -24,15 +34,15 @@ normalizes to exactly zero (``win - mu == 0``), so the LB cascade and DTW
 stay finite on flat reference segments instead of producing inf/NaN.
 
 Non-finite quarantine (DESIGN.md §2.6): the prefix sums above are the reason
-a single NaN sample is catastrophic without a prepass — ``cumsum`` carries
-it into the stats of *every* later window. The quarantine contract is
-implemented right here at the stats layer: ``window_finite_mask`` marks the
-windows overlapping any non-finite sample (one more prefix-sum pass, same
-O(N) shape as the stats themselves), and ``sanitize_series`` zero-fills the
-bad samples so the stats/cascade arithmetic of the *surviving* windows is
-untouched by them. Drivers kill the masked windows through the dead-lane
-sentinel (``+inf`` lower bound) and report the count; everything outside a
-quarantined window stays exact.
+a single NaN sample is harmful beyond its own windows without a prepass —
+``cumsum`` carries it into the stats of every window that shares its block.
+The quarantine contract is implemented right here at the stats layer:
+``window_finite_mask`` marks the windows overlapping any non-finite sample
+(one more prefix-sum pass, same O(N) shape as the stats themselves), and
+``sanitize_series`` zero-fills the bad samples so the stats/cascade
+arithmetic of the *surviving* windows is untouched by them. Drivers kill
+the masked windows through the dead-lane sentinel (``+inf`` lower bound)
+and report the count; everything outside a quarantined window stays exact.
 """
 from __future__ import annotations
 
@@ -67,15 +77,32 @@ def window_stats(ref: jax.Array, length: int) -> tuple[jax.Array, jax.Array]:
     Returns ``(mu, sigma)`` of shape ``(N - length + 1,)`` each. ``sigma`` is
     raw (unclamped): exactly zero on a constant window. Divide only through
     ``clamp_sigma``.
+
+    The sums of ``x`` and ``x * x`` come from prefix sums taken within
+    blocks of ``length`` samples, never over the whole series: window
+    ``s = b * length + o`` is the tail of block ``b`` from ``o`` plus the
+    head of block ``b + 1`` up to ``o``, so no difference is taken between
+    sums of more than ``length`` samples, and the float32 error does not
+    grow with the series (module docstring, "Precision").
     """
     n = ref.shape[0]
-    p = jnp.concatenate([jnp.zeros((1,), ref.dtype), jnp.cumsum(ref)])
-    q = jnp.concatenate([jnp.zeros((1,), ref.dtype), jnp.cumsum(ref * ref)])
-    starts = jnp.arange(n - length + 1)
-    s1 = p[starts + length] - p[starts]
-    s2 = q[starts + length] - q[starts]
-    mu = s1 / length
-    var = jnp.maximum(s2 / length - mu * mu, 0.0)
+    n_win = n - length + 1
+    n_blocks = n // length + 1  # one more block than windows need
+    pad = jnp.zeros((n_blocks * length - n,), ref.dtype)
+
+    def window_sums(x):
+        blocks = jnp.concatenate([x, pad]).reshape(n_blocks, length)
+        incl = jnp.cumsum(blocks, axis=1)
+        # head[b * length + o]: the block's sum before offset o;
+        # total[b * length + o]: the whole block's sum.
+        head = jnp.concatenate(
+            [jnp.zeros((n_blocks, 1), x.dtype), incl[:, :-1]], axis=1
+        ).reshape(-1)
+        total = jnp.repeat(incl[:, -1], length)
+        return (total[:n_win] - head[:n_win]) + head[length : length + n_win]
+
+    mu = window_sums(ref) / length
+    var = jnp.maximum(window_sums(ref * ref) / length - mu * mu, 0.0)
     return mu, jnp.sqrt(var)
 
 
@@ -91,8 +118,7 @@ def append_window_stats(
     ending inside the new chunk, including the ``length - 1`` windows
     straddling the tail/chunk boundary — and ``new_tail`` is the context to
     carry into the next append. Cost is O(tail + chunk) regardless of how
-    long the stream already is; the boundary-local prefix sums also avoid the
-    precision loss of differencing a billion-sample running cumsum.
+    long the stream already is.
 
     Zero windows may be valid yet (stream shorter than ``length``): then the
     stats arrays are empty and ``new_tail`` is the whole stream so far.
